@@ -26,6 +26,7 @@ os.environ.setdefault("XLA_FLAGS", "--xla_force_host_platform_device_count=8")
 import jax  # noqa: E402
 import numpy as np  # noqa: E402
 
+from repro.launch.mesh import make_host_mesh  # noqa: E402
 from repro.serve import (AnalyticalPolicy, ComposedServer,  # noqa: E402
                          ServeConfig, TenantSpec)
 
@@ -46,7 +47,7 @@ def heterogeneous_fleet():
     8 CUs under the class-aware analytical policy — each priced by its bound
     resource (weight bandwidth / state bandwidth / compute / decode GEMV +
     per-step cross-attention source reads)."""
-    mesh = jax.make_mesh((1, 8), ("data", "model"))
+    mesh = make_host_mesh((1, 8), ("data", "model"))
     serve = ServeConfig(max_slots=2, max_len=48, eos_id=-1)
     s2t_serve = ServeConfig(max_slots=2, max_len=24, eos_id=-1,
                             max_src_len=32, len_buckets=(16,))
@@ -101,7 +102,7 @@ def heterogeneous_fleet():
 
 
 def main():
-    mesh = jax.make_mesh((1, 8), ("data", "model"))
+    mesh = make_host_mesh((1, 8), ("data", "model"))
     serve = ServeConfig(max_slots=2, max_len=64, eos_id=-1)
     server = ComposedServer(
         mesh,

@@ -1,9 +1,13 @@
-from repro.common.platform import PROFILES, TPU_V5E, VCK190, PlatformProfile, get_profile
+from repro.common.platform import (DEVICE_PROFILES, PROFILES, TPU_V5E, VCK190,
+                                   PlatformProfile, device_profile,
+                                   get_profile)
 
 __all__ = [
+    "DEVICE_PROFILES",
     "PROFILES",
     "TPU_V5E",
     "VCK190",
     "PlatformProfile",
+    "device_profile",
     "get_profile",
 ]

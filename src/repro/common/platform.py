@@ -111,6 +111,33 @@ TPU_V5E = PlatformProfile(
 
 PROFILES = {p.name: p for p in (VCK190, TPU_V5E)}
 
+# ``device_kind`` as JAX reports it -> the profile of that chip.  A kind
+# missing here is an error, never a default: pricing another chip with v5e
+# peaks would skew every split the serving policy picks.
+DEVICE_PROFILES = {
+    "TPU v5 lite": TPU_V5E,
+}
+
 
 def get_profile(name: str) -> PlatformProfile:
     return PROFILES[name]
+
+
+def device_profile(device=None) -> PlatformProfile:
+    """Profile of ``device`` (default: JAX's first device).
+
+    TPU kinds resolve through ``DEVICE_PROFILES`` and an unknown kind
+    raises.  The CPU backend (tests, reduced runs) has no peaks of its own
+    to price: there the model prices the deployment target, ``TPU_V5E``."""
+    if device is None:
+        import jax
+        device = jax.devices()[0]
+    if device.platform == "cpu":
+        return TPU_V5E
+    try:
+        return DEVICE_PROFILES[device.device_kind]
+    except KeyError:
+        raise ValueError(
+            f"no PlatformProfile for device kind {device.device_kind!r} "
+            f"({device.platform}); add its peaks to DEVICE_PROFILES in "
+            f"repro/common/platform.py") from None

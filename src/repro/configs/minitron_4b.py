@@ -18,6 +18,11 @@ CONFIG = ModelConfig(
     attn_type="full",
     act="relu2",
     glu=False,
+    # bf16 weights: 4.19B parameters are 16.8 GB in float32, more than one
+    # v5e chip's 16 GiB of HBM; in bf16 they are 8.4 GB.  Every layer casts
+    # weights to the activation dtype (bf16) where it uses them, so the
+    # forward pass computes the same either way.
+    param_dtype="bfloat16",
 )
 
 REDUCED = ModelConfig(

@@ -339,6 +339,14 @@ class ShardingPlan:
     def annotated(self) -> bool:
         return any(l is not None for l in self.logicals)
 
+    def annotate(self, tree):
+        """Re-wrap a stripped tree's leaves with this plan's logical specs
+        (the inverse of :func:`strip`)."""
+        leaves = self.treedef.flatten_up_to(tree)
+        return self.treedef.unflatten(
+            [v if l is None else Annotated(v, l)
+             for v, l in zip(leaves, self.logicals)])
+
     def specs(self, mesh: Mesh, rules: ShardingRules) -> list:
         return [fit_spec(rules.spec(l) if l is not None else P(), shape, mesh)
                 for shape, l in zip(self.shapes, self.logicals)]

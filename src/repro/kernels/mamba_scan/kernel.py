@@ -63,8 +63,11 @@ def _step_kernel(live_ref, x_ref, conv_ref, h_ref, inproj_ref, convw_ref,
     def _step():
         x = x_ref[...]                                       # (1, d_model)
         dtype = x.dtype
+        # every dot accumulates in f32 (Mosaic's MXU contract) and rounds
+        # to the activation dtype where the unfused chain does
         xz = jax.lax.dot_general(
-            x, inproj_ref[...].astype(dtype), (((1,), (0,)), ((), ())))
+            x, inproj_ref[...].astype(dtype), (((1,), (0,)), ((), ())),
+            preferred_element_type=f32).astype(dtype)
         d_in = xz.shape[1] // 2
         xp, z = xz[:, :d_in], xz[:, d_in:]                   # (1, d_in)
         window = jnp.concatenate(
@@ -73,25 +76,30 @@ def _step_kernel(live_ref, x_ref, conv_ref, h_ref, inproj_ref, convw_ref,
                      axis=0, keepdims=True) + convb_ref[...].astype(f32)
         x_conv = jax.nn.silu(xc).astype(dtype)               # (1, d_in)
         dbc = jax.lax.dot_general(
-            x_conv, xproj_ref[...].astype(dtype), (((1,), (0,)), ((), ())))
+            x_conv, xproj_ref[...].astype(dtype), (((1,), (0,)), ((), ())),
+            preferred_element_type=f32).astype(dtype)
         dt_raw = dbc[:, :dt_rank]
         b_ssm = dbc[:, dt_rank:dt_rank + state_dim].astype(f32)
         c_ssm = dbc[:, dt_rank + state_dim:].astype(f32)     # (1, N)
         dt = jax.nn.softplus(
             jax.lax.dot_general(dt_raw, dtproj_ref[...].astype(dtype),
-                                (((1,), (0,)), ((), ()))).astype(f32)
+                                (((1,), (0,)), ((), ())),
+                                preferred_element_type=f32)
+            .astype(dtype).astype(f32)
             + dtbias_ref[...].astype(f32))                   # (1, d_in)
         a = -jnp.exp(alog_ref[...].astype(f32))              # (d_in, N)
         dt_col = jnp.reshape(dt, (d_in, 1))
         da = jnp.exp(dt_col * a)
         xcol = jnp.reshape(x_conv.astype(f32), (d_in, 1))
         h_new = da * h_ref[...] + (dt_col * xcol) * b_ssm    # (d_in, N)
-        y = jax.lax.dot_general(h_new, c_ssm, (((1,), (1,)), ((), ())))
+        y = jax.lax.dot_general(h_new, c_ssm, (((1,), (1,)), ((), ())),
+                                preferred_element_type=f32)
         y = jnp.reshape(y, (1, d_in)) \
             + dvec_ref[...].astype(f32) * x_conv.astype(f32)
         y = (y * jax.nn.silu(z.astype(f32))).astype(dtype)
         o_ref[...] = jax.lax.dot_general(
-            y, outproj_ref[...].astype(dtype), (((1,), (0,)), ((), ())))
+            y, outproj_ref[...].astype(dtype), (((1,), (0,)), ((), ())),
+            preferred_element_type=f32).astype(o_ref.dtype)
         nconv_ref[...] = window[1:].astype(nconv_ref.dtype)
         nh_ref[...] = h_new
 

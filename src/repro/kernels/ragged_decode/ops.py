@@ -9,14 +9,28 @@ The serving engines call this through ``gqa_step``/``cross_step`` when
   that slice the cache before calling here);
 * ``impl="ref"`` — the oracle;
 * ``impl="interpret"`` — the Pallas kernel in interpreter mode (CPU CI).
+
+Mosaic kernels cannot be partitioned by XLA's SPMD pass.  When the caller
+traces under an abstract mesh with more than one device
+(``jax.sharding.use_abstract_mesh`` — the decode engine sets it while
+lowering for its sub-mesh), the kernel runs under ``jax.shard_map``: each
+device attends with its own shard of the heads along ``HEAD_AXIS``, or with
+every head when the KV head count does not divide that axis.
 """
 from __future__ import annotations
 
+import functools
+
 import jax
 import jax.numpy as jnp
+from jax.sharding import PartitionSpec as P
 
 from repro.kernels.ragged_decode import kernel as K
 from repro.kernels.ragged_decode import ref as R
+
+# the mesh axis the serving sharding rules split attention heads and the
+# KV cache's heads over (``serve_engine_rules``: heads/kv_heads -> model)
+HEAD_AXIS = "model"
 
 
 def _on_tpu() -> bool:
@@ -28,6 +42,21 @@ def _block(T: int, bk: int) -> int:
     while T % bk:
         bk //= 2
     return max(bk, 1)
+
+
+def _sharded(fn, hkv: int):
+    """``fn`` under shard_map on the tracing context's abstract mesh, with
+    heads split over HEAD_AXIS where they divide it; ``fn`` itself when
+    there is no multi-device mesh to partition over."""
+    mesh = jax.sharding.get_abstract_mesh()
+    if mesh.empty or mesh.size <= 1:
+        return fn
+    ax = (HEAD_AXIS if HEAD_AXIS in mesh.axis_names
+          and hkv % mesh.shape[HEAD_AXIS] == 0 else None)
+    q_spec, kv_spec = P(None, ax, None), P(None, None, ax, None)
+    return jax.shard_map(fn, mesh=mesh,
+                         in_specs=(q_spec, kv_spec, kv_spec, P(), P(), P()),
+                         out_specs=q_spec, check_vma=False)
 
 
 def ragged_decode_attention(q, k, v, lengths, *, window: int = 0,
@@ -52,7 +81,8 @@ def ragged_decode_attention(q, k, v, lengths, *, window: int = 0,
         glob = jnp.zeros((1,), jnp.int32)
     else:
         glob = jnp.reshape(jnp.asarray(is_global).astype(jnp.int32), (1,))
-    out = K.ragged_decode_kernel(
-        q[:, 0], k, v, lens, live_i, glob, window=window,
-        logit_cap=logit_cap, bk=_block(T, bk), interpret=interpret)
+    kernel = functools.partial(
+        K.ragged_decode_kernel, window=window, logit_cap=logit_cap,
+        bk=_block(T, bk), interpret=interpret)
+    out = _sharded(kernel, k.shape[2])(q[:, 0], k, v, lens, live_i, glob)
     return out[:, None]

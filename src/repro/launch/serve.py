@@ -47,10 +47,11 @@ import time
 import jax
 import numpy as np
 
+from repro.common.jax_cache import setup_compile_cache
 from repro.configs import ARCH_IDS, get_config, get_reduced
 from repro.configs.base import ModelConfig
 from repro.core.composer import MeshComposer
-from repro.launch.mesh import make_production_mesh
+from repro.launch.mesh import make_host_mesh, make_production_mesh
 from repro.models import build_model
 from repro.serve import (AnalyticalPolicy, ComposedServer, ReplicaGroup,
                          SLOTarget, ServeConfig, ServeEngine,
@@ -113,7 +114,7 @@ def run_fabric(args) -> int:
     """Traffic-driven multi-tenant serving on one recomposable fabric."""
     mesh = (make_production_mesh(multi_pod=args.multi_pod)
             if args.production_mesh else
-            jax.make_mesh((1, jax.device_count()), ("data", "model")))
+            make_host_mesh((1, jax.device_count())))
     serve = ServeConfig(max_slots=args.max_slots, max_len=args.max_len,
                         eos_id=-1, kv_arena_frac=args.kv_frac,
                         kv_page_rows=args.kv_page_rows)
@@ -281,7 +282,7 @@ def run_scaling(args) -> int:
     cfg = bench_config(args.scale_dmodel, args.scale_layers, args.scale_dff)
     model = build_model(cfg)
     params = model.init(jax.random.key(args.seed))
-    mesh = jax.make_mesh((1, jax.device_count()), ("data", "model"))
+    mesh = make_host_mesh((1, jax.device_count()))
     comp = MeshComposer(mesh)
     rules = None if args.no_tp else serve_engine_rules()
     sizes = [s for s in args.scale_sizes if s <= comp.num_cus]
@@ -346,7 +347,7 @@ def run_dse_smoke(args) -> int:
         print("dse-smoke needs >= 4 devices "
               "(XLA_FLAGS=--xla_force_host_platform_device_count=8)")
         return 2
-    mesh = jax.make_mesh((1, jax.device_count()), ("data", "model"))
+    mesh = make_host_mesh((1, jax.device_count()))
     sc = ServeConfig(max_slots=2, max_len=48, eos_id=-1)
     # a: small model, batch capped at 4 slots/engine -> a deep queue on a
     # wide grant is only servable by replica tiling (the dp axis)
@@ -411,7 +412,7 @@ def run_obs_smoke(args) -> int:
         print("obs-smoke needs >= 4 devices "
               "(XLA_FLAGS=--xla_force_host_platform_device_count=8)")
         return 2
-    mesh = jax.make_mesh((1, jax.device_count()), ("data", "model"))
+    mesh = make_host_mesh((1, jax.device_count()))
     serve = ServeConfig(max_slots=2, max_len=64, eos_id=-1)
     tenants = [TenantSpec(f"{w}-{arch}", arch, reduced=True, serve=serve,
                           seed=i, workload=w)
@@ -501,7 +502,7 @@ def run_slo_smoke(args) -> int:
         print("slo-smoke needs >= 4 devices "
               "(XLA_FLAGS=--xla_force_host_platform_device_count=8)")
         return 2
-    mesh = jax.make_mesh((1, jax.device_count()), ("data", "model"))
+    mesh = make_host_mesh((1, jax.device_count()))
     requests, mnew = max(args.requests, 6), 24
 
     def build(paged: bool) -> ComposedServer:
@@ -602,7 +603,7 @@ def run_dp_bench(args) -> int:
     cfg = bench_config(512, 6, 4096)
     model = build_model(cfg)
     params = model.init(jax.random.key(args.seed))
-    mesh = jax.make_mesh((1, jax.device_count()), ("data", "model"))
+    mesh = make_host_mesh((1, jax.device_count()))
     comp = MeshComposer(mesh)
     grant, queue, M, reps = 4, 16, args.scale_steps, 3
     sc = ServeConfig(max_slots=4, max_len=4096, eos_id=-1, slot_cap=4)
@@ -674,7 +675,7 @@ def run_tp_smoke(args) -> int:
     cfg = dataclasses.replace(get_reduced("minitron-4b"), dtype="float32")
     model = build_model(cfg)
     params = model.init(jax.random.key(0))
-    mesh = jax.make_mesh((1, jax.device_count()), ("data", "model"))
+    mesh = make_host_mesh((1, jax.device_count()))
     comp = MeshComposer(mesh)
     sc = ServeConfig(max_slots=2, max_len=64, eos_id=-1)
     rng = np.random.default_rng(args.seed)
@@ -710,6 +711,7 @@ def run_tp_smoke(args) -> int:
 
 
 def main(argv=None) -> int:
+    setup_compile_cache()
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", choices=ARCH_IDS, action="append",
                     help="repeat for multiple tenants with --fabric")

@@ -14,7 +14,8 @@ import jax
 import jax.numpy as jnp
 
 from repro.configs.base import ModelConfig, ShapeCell
-from repro.distribution.partitioning import Annotated
+from repro.distribution.partitioning import (Annotated, ShardingPlan,
+                                             ShardingRules)
 from repro.models import layers as L
 from repro.models import transformer as T
 
@@ -33,31 +34,25 @@ class Model:
     cfg: ModelConfig
 
     # ------------------------------------------------------------------
-    def init(self, rng) -> PyTree:
-        cfg = self.cfg
-        ks = jax.random.split(rng, 6)
-        params: Dict[str, PyTree] = {
-            "embed": _embed_init(ks[0], cfg),
-            "decoder": T.decoder_init(ks[1], cfg, cross=cfg.cross_attention),
-            "final_norm": L.norm_init(cfg.norm, cfg.d_model),
-        }
-        if not cfg.tie_embeddings:
-            params["lm_head"] = L.dense_init(
-                ks[2], cfg.d_model, cfg.padded_vocab, ("embed", "vocab"),
-                std=cfg.d_model ** -0.5)
-        if cfg.is_encdec:
-            params["encoder"] = T.encoder_init(ks[3], cfg)
-            if cfg.frontend == "frames":
-                params["frame_norm"] = L.norm_init(cfg.norm, cfg.d_model)
-        pd = jnp.dtype(self.cfg.param_dtype)
-        if pd != jnp.float32:
-            params = jax.tree.map(
-                lambda a: Annotated(
-                    a.value.astype(pd)
-                    if jnp.issubdtype(a.value.dtype, jnp.floating) else a.value,
-                    a.logical),
-                params, is_leaf=lambda x: isinstance(x, Annotated))
-        return params
+    def init(self, rng, mesh=None, rules=None) -> PyTree:
+        """Annotated parameter tree from ``rng``.
+
+        One jitted program draws every leaf in float32 and casts it to
+        ``cfg.param_dtype`` inside the program, so the device only ever
+        holds the cast tree (a 4B-parameter model in bf16 is 8.4 GB; its
+        float32 tree would not fit one 16 GB chip).  The values are those
+        of a float32 init cast afterwards.
+
+        ``mesh`` (with ``rules``; default: replicated) creates each leaf
+        directly with its sharding over that mesh, instead of placing the
+        whole tree on the default device first; the values are the same
+        either way."""
+        if mesh is None:
+            return _init(self.cfg, rng)
+        plan = ShardingPlan.of(jax.eval_shape(_init, self.cfg, rng))
+        out = plan.shardings(mesh, rules or ShardingRules(rules={}))
+        return jax.jit(_init_tree, static_argnums=0,
+                       out_shardings=out)(self.cfg, rng)
 
     # ------------------------------------------------------------------
     def _head(self, params):
@@ -254,6 +249,35 @@ class Model:
         if cfg.is_encdec:
             new_cache["src_len"] = src_len
         return logits, new_cache
+
+
+def _init_tree(cfg: ModelConfig, rng) -> PyTree:
+    ks = jax.random.split(rng, 6)
+    params: Dict[str, PyTree] = {
+        "embed": _embed_init(ks[0], cfg),
+        "decoder": T.decoder_init(ks[1], cfg, cross=cfg.cross_attention),
+        "final_norm": L.norm_init(cfg.norm, cfg.d_model),
+    }
+    if not cfg.tie_embeddings:
+        params["lm_head"] = L.dense_init(
+            ks[2], cfg.d_model, cfg.padded_vocab, ("embed", "vocab"),
+            std=cfg.d_model ** -0.5)
+    if cfg.is_encdec:
+        params["encoder"] = T.encoder_init(ks[3], cfg)
+        if cfg.frontend == "frames":
+            params["frame_norm"] = L.norm_init(cfg.norm, cfg.d_model)
+    pd = jnp.dtype(cfg.param_dtype)
+    if pd != jnp.float32:
+        params = jax.tree.map(
+            lambda a: Annotated(
+                a.value.astype(pd)
+                if jnp.issubdtype(a.value.dtype, jnp.floating) else a.value,
+                a.logical),
+            params, is_leaf=lambda x: isinstance(x, Annotated))
+    return params
+
+
+_init = jax.jit(_init_tree, static_argnums=0)
 
 
 def build_model(cfg: ModelConfig) -> Model:

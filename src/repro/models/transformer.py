@@ -232,13 +232,6 @@ def _layer_step(p, cfg, x1, cache, pos, *, is_global=None, src_len=None,
 # stacks
 # ---------------------------------------------------------------------------
 
-def _stack_layers(layer_list):
-    """List of identically-structured layer pytrees -> stacked pytree."""
-    return jax.tree.map(
-        lambda *xs: Annotated(jnp.stack([x.value for x in xs]), xs[0].logical),
-        *layer_list, is_leaf=lambda x: isinstance(x, Annotated))
-
-
 def _prologue_plan(cfg: ModelConfig) -> Tuple[int, int]:
     """(num_prologue, num_scanned)."""
     k = cfg.moe.first_k_dense if cfg.moe is not None else 0
@@ -258,8 +251,11 @@ def decoder_init(rng, cfg: ModelConfig, *, cross: bool = False):
                     dense_override_ff=cfg.moe.first_dense_d_ff if cfg.moe else 0)
         for i in range(n_pro)
     ]
-    scanned = _stack_layers([_layer_init(ks[n_pro + i], cfg, cross=cross)
-                             for i in range(n_scan)])
+    # one vmapped init (the same values as a per-layer loop + stack) keeps
+    # the init program's size flat in depth: it compiles in seconds, not
+    # minutes, at 32+ layers
+    scanned = jax.vmap(lambda k: _layer_init(k, cfg, cross=cross))(
+        ks[n_pro:])
     # annotate stacked leaves with the leading layer axis
     scanned = jax.tree.map(
         lambda a: Annotated(a.value, ("layers",) + tuple(a.logical)),
@@ -431,7 +427,7 @@ def decoder_step(params, cfg: ModelConfig, x1, cache, *, src_len=None,
 
 def encoder_init(rng, cfg: ModelConfig):
     ks = jax.random.split(rng, cfg.encoder_layers)
-    scanned = _stack_layers([_layer_init(ks[i], cfg) for i in range(cfg.encoder_layers)])
+    scanned = jax.vmap(lambda k: _layer_init(k, cfg))(ks)
     scanned = jax.tree.map(
         lambda a: Annotated(a.value, ("layers",) + tuple(a.logical)),
         scanned, is_leaf=lambda x: isinstance(x, Annotated))

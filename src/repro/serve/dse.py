@@ -30,7 +30,7 @@ from __future__ import annotations
 import dataclasses
 from typing import Callable, Dict, Mapping, Optional, Sequence, Tuple
 
-from repro.common.platform import PlatformProfile, TPU_V5E
+from repro.common.platform import PlatformProfile, device_profile
 from repro.configs.base import ModelConfig
 from repro.core.analytical import dp_dispatch_overhead, tp_collective_latency
 from repro.core.dse import DesignPoint, dp_candidates, tp_candidates
@@ -131,10 +131,12 @@ class Stage1Optimizer:
     """
 
     def __init__(self, step_cost: Callable,
-                 platform: PlatformProfile = TPU_V5E, *,
+                 platform: Optional[PlatformProfile] = None, *,
                  slot_choices: Tuple[int, ...] = (1, 2, 4, 8, 16),
                  mem_budget_bytes: Optional[float] = None):
         self.step_cost = step_cost
+        # None: the chip this process runs on (an unknown TPU kind raises)
+        platform = platform or device_profile()
         self.platform = platform
         self.slot_choices = tuple(sorted(set(slot_choices)))
         # HBM a tenant's slot pool may pin per granted CU (params, single

@@ -40,7 +40,7 @@ from typing import Any, Dict, List, Mapping, Optional, Sequence, Tuple
 
 import jax
 
-from repro.common.platform import TPU_V5E, PlatformProfile
+from repro.common.platform import PlatformProfile, device_profile
 from repro.configs import get_config, get_reduced
 from repro.configs.base import ModelConfig
 from repro.core.analytical import (AccelConfig, decode_kv_read_latency,
@@ -250,9 +250,10 @@ class AnalyticalPolicy:
     decide intervals.
     """
 
-    def __init__(self, platform: PlatformProfile = TPU_V5E,
+    def __init__(self, platform: Optional[PlatformProfile] = None,
                  min_gain: float = 1.25, two_stage: bool = True):
-        self.platform = platform
+        # None: the chip this process runs on (an unknown TPU kind raises)
+        self.platform = platform or device_profile()
         self.min_gain = min_gain
         self._cost_cache: Dict[Tuple, float] = {}
         self.runner_up: Optional[Dict[str, DesignPoint]] = None
@@ -604,7 +605,6 @@ class ReplicaGroup:
         self._wclass = wclass
         self.workload_class = wclass
         self._model = model
-        self._params = params            # annotated: grows fresh replicas
         self._serve_cfg = serve_cfg
         self._rules = rules
         self._exec = (exec_cache if exec_cache is not None
@@ -1003,7 +1003,11 @@ class ReplicaGroup:
                   else d0["buckets"])
         if ladder:
             cfg = dataclasses.replace(cfg, len_buckets=tuple(ladder))
-        eng = build_engine(self._wclass, self._model, self._params, cfg,
+        # copy replica 0's live params: the group holds no tree of its own,
+        # which would pin a stale full copy on its first devices
+        eng0 = self._replicas[0].engine
+        params = eng0._param_plan.annotate(eng0.params)
+        eng = build_engine(self._wclass, self._model, params, cfg,
                            mesh=mesh, rules=self._rules,
                            exec_cache=self._exec, obs=obs)
         tp = eng_point.tp if eng_point.tp is not None else d0["tp"]
@@ -1147,7 +1151,11 @@ class ComposedServer:
             cfg = (get_reduced(spec.arch) if spec.reduced
                    else get_config(spec.arch))
             model = build_model(cfg)
-            params = model.init(jax.random.key(spec.seed))  # annotated: TP
+            # annotated (TP plans need the logical specs), and made on the
+            # tenant's own sub-mesh: no full copy ever lands on device 0
+            params = model.init(jax.random.key(spec.seed),
+                                mesh=self.subs[spec.name].mesh,
+                                rules=self.rules)
             wclass = (workload_class_of(cfg) if spec.workload == "auto"
                       else spec.workload)
             self.cfgs[spec.name] = cfg

@@ -80,6 +80,12 @@ class ExecutableCache:
             return {"builds": self.builds, "hits": self.hits,
                     "size": len(self._exe), "capacity": self.capacity}
 
+    def items(self) -> list:
+        """Snapshot of the cached (key, executable) pairs, least recently
+        used first (inspection: e.g. which kernels a program compiled)."""
+        with self._lock:
+            return list(self._exe.items())
+
     def _insert(self, key: Hashable, exe: Any) -> None:
         with self._lock:
             if key not in self._exe:

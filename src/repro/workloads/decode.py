@@ -36,6 +36,7 @@ Three properties make the engine a real-time-recomposable accelerator
 """
 from __future__ import annotations
 
+import contextlib
 import dataclasses
 import functools
 import itertools
@@ -868,14 +869,19 @@ class DecodeEngine(EngineTelemetry):
         step = functools.partial(
             self._decode_fn, **dict(zip(("kv_bound", "src_bound"), bounds)))
         fn = jax.jit(step, donate_argnums=(1,), **kwargs)
-        return fn.lower(
-            self._param_plan.avals(mesh, rules),
-            plan.avals(mesh, rules),
-            self._vec_aval(mesh, jnp.int32, (B,)),
-            self._vec_aval(mesh, jnp.int32, (B,)),
-            self._vec_aval(mesh, jnp.bool_, (B,)),
-            self._vec_aval(mesh, jnp.bool_, (B,)),
-        ).compile()
+        # trace under the sub-mesh so the decode kernels wrap themselves in
+        # shard_map (XLA cannot partition a Mosaic kernel on its own)
+        ctx = (jax.sharding.use_abstract_mesh(mesh.abstract_mesh)
+               if mesh is not None else contextlib.nullcontext())
+        with ctx:
+            lowered = fn.lower(
+                self._param_plan.avals(mesh, rules),
+                plan.avals(mesh, rules),
+                self._vec_aval(mesh, jnp.int32, (B,)),
+                self._vec_aval(mesh, jnp.int32, (B,)),
+                self._vec_aval(mesh, jnp.bool_, (B,)),
+                self._vec_aval(mesh, jnp.bool_, (B,)))
+        return lowered.compile()
 
     def _build_prefill(self, mesh, nb: int, slots: Optional[int] = None):
         plan = self._plan_for_slots(slots or self.cfg.max_slots)

@@ -18,6 +18,7 @@ import json
 import jax
 import jax.numpy as jnp
 import numpy as np
+from repro.launch.mesh import make_host_mesh
 """
 
 
@@ -56,7 +57,7 @@ def test_sharded_train_step_matches_single_device():
     p1, o1, m1 = step(params0, opt0, jnp.asarray(0), batch)
 
     # sharded on a (2 data, 4 model) mesh
-    mesh = jax.make_mesh((2, 4), ("data", "model"))
+    mesh = make_host_mesh((2, 4), ("data", "model"))
     rules = part.train_rules(sequence_parallel=False)
     params, opt_state, psh, osh = setup_sharded_state(
         model, opt, mesh, rules, jax.random.key(0))
@@ -78,14 +79,14 @@ def test_elastic_checkpoint_reshard():
     from jax.sharding import NamedSharding, PartitionSpec as P
     from repro.train import checkpoint as ck
 
-    mesh_a = jax.make_mesh((2, 4), ("data", "model"))
+    mesh_a = make_host_mesh((2, 4), ("data", "model"))
     tree = {"w": jnp.arange(64, dtype=jnp.float32).reshape(8, 8)}
     sharded = jax.device_put(
         tree, {"w": NamedSharding(mesh_a, P("data", "model"))})
     with tempfile.TemporaryDirectory() as d:
         ck.save(d, 1, sharded, extra={"mesh": [2, 4]})
         # restore onto a DIFFERENT mesh shape (elastic restart)
-        mesh_b = jax.make_mesh((4, 2), ("data", "model"))
+        mesh_b = make_host_mesh((4, 2), ("data", "model"))
         got, extra = ck.restore(
             d, 1, tree,
             shardings={"w": NamedSharding(mesh_b, P("model", "data"))})
@@ -102,14 +103,10 @@ def test_compressed_psum_cross_pod():
     from functools import partial
     from repro.optim import compressed_psum, ErrorFeedback
 
-    shard_map = getattr(jax, "shard_map", None)  # jax >= 0.5 spelling
-    if shard_map is None:
-        from jax.experimental.shard_map import shard_map
-
-    mesh = jax.make_mesh((8,), ("pod",))
+    mesh = make_host_mesh((8,), ("pod",))
     x = np.random.default_rng(1).normal(size=(8, 64)).astype(np.float32)
 
-    @partial(shard_map, mesh=mesh,
+    @partial(jax.shard_map, mesh=mesh,
              in_specs=jax.sharding.PartitionSpec("pod"),
              out_specs=jax.sharding.PartitionSpec("pod"))
     def reduce_compressed(xs):
@@ -127,7 +124,7 @@ def test_compressed_psum_cross_pod():
 def test_mesh_composer_partitions_devices():
     res = _run("""
     from repro.core.composer import MeshComposer, split_axis
-    mesh = jax.make_mesh((2, 4), ("data", "model"))
+    mesh = make_host_mesh((2, 4), ("data", "model"))
     comp = MeshComposer(mesh, cu_axis="model")
     subs = comp.compose([2, 1, 1], names=["big", "mid", "small"])
     sizes = [s.mesh.devices.size for s in subs]
@@ -151,7 +148,7 @@ def test_multi_tenant_two_models_on_submeshes():
     from repro.distribution import partitioning as part
     from repro.models import build_model
 
-    mesh = jax.make_mesh((1, 8), ("data", "model"))
+    mesh = make_host_mesh((1, 8), ("data", "model"))
     comp = MeshComposer(mesh, cu_axis="model")
     sub_a, sub_b = comp.compose([4, 4], names=["tenant-a", "tenant-b"])
 

@@ -144,6 +144,7 @@ sys.path.insert(0, "src")
 import json
 import jax
 import numpy as np
+from repro.launch.mesh import make_host_mesh
 """
 
 
@@ -165,7 +166,7 @@ def test_recomposition_preserves_decode_numerics():
     from repro.models import build_model
     from repro.serve import ServeConfig, ServeEngine
 
-    mesh = jax.make_mesh((1, 8), ("data", "model"))
+    mesh = make_host_mesh((1, 8), ("data", "model"))
     comp = MeshComposer(mesh)
     cfg = get_reduced("minitron-4b")
     sc = ServeConfig(max_slots=2, max_len=64, eos_id=-1)
@@ -204,7 +205,7 @@ def test_composed_server_delta_leaves_unmoved_tenant_devices():
     from repro.serve.fabric import ComposedServer, TenantSpec
     from repro.serve import ServeConfig
 
-    mesh = jax.make_mesh((1, 8), ("data", "model"))
+    mesh = make_host_mesh((1, 8), ("data", "model"))
     sc = ServeConfig(max_slots=2, max_len=32, eos_id=-1)
     srv = ComposedServer(mesh, [
         TenantSpec("a", "minitron-4b", serve=sc),
@@ -244,7 +245,7 @@ def test_tp_decode_equivalence_across_degrees():
     from repro.models import build_model
     from repro.serve import ServeConfig, ServeEngine, serve_engine_rules
 
-    mesh = jax.make_mesh((1, 8), ("data", "model"))
+    mesh = make_host_mesh((1, 8), ("data", "model"))
     comp = MeshComposer(mesh)
     # fp32: greedy argmax must be reduction-order-proof across TP degrees
     cfg = dataclasses.replace(get_reduced("minitron-4b"), dtype="float32")
@@ -291,7 +292,7 @@ def test_warm_recompose_skips_post_move_compile():
     from repro.serve.fabric import ComposedServer, TenantSpec
     from repro.serve import ServeConfig
 
-    mesh = jax.make_mesh((1, 8), ("data", "model"))
+    mesh = make_host_mesh((1, 8), ("data", "model"))
     sc = ServeConfig(max_slots=2, max_len=32, eos_id=-1)
     srv = ComposedServer(mesh, [
         TenantSpec("a", "minitron-4b", serve=sc),
@@ -340,7 +341,7 @@ def test_prewarm_async_commits_after_background_compile():
                                     TenantSpec)
     from repro.serve import ServeConfig
 
-    mesh = jax.make_mesh((1, 8), ("data", "model"))
+    mesh = make_host_mesh((1, 8), ("data", "model"))
     sc = ServeConfig(max_slots=2, max_len=64, eos_id=-1)
     srv = ComposedServer(mesh, [
         TenantSpec("a", "minitron-4b", serve=sc),
@@ -384,7 +385,7 @@ def test_replica_group_routing_and_merged_stats():
     from repro.serve import ReplicaGroup, ServeConfig, serve_engine_rules
     from repro.workloads import DECODE
 
-    mesh = jax.make_mesh((1, 8), ("data", "model"))
+    mesh = make_host_mesh((1, 8), ("data", "model"))
     comp = MeshComposer(mesh)
     cfg = get_reduced("minitron-4b")
     model = build_model(cfg)
@@ -440,7 +441,7 @@ def test_dp_replica_streams_bit_identical():
     from repro.serve import ReplicaGroup, ServeConfig, serve_engine_rules
     from repro.workloads import DECODE
 
-    mesh = jax.make_mesh((1, 8), ("data", "model"))
+    mesh = make_host_mesh((1, 8), ("data", "model"))
     comp = MeshComposer(mesh)
     cfg = get_reduced("minitron-4b")
     model = build_model(cfg)
@@ -486,7 +487,7 @@ def test_traffic_driven_autoscale_end_to_end():
                                     TenantSpec)
     from repro.serve import ServeConfig
 
-    mesh = jax.make_mesh((1, 8), ("data", "model"))
+    mesh = make_host_mesh((1, 8), ("data", "model"))
     sc = ServeConfig(max_slots=2, max_len=64, eos_id=-1)
     srv = ComposedServer(mesh, [
         TenantSpec("a", "minitron-4b", serve=sc),

@@ -94,8 +94,9 @@ _SUBPROC = textwrap.dedent("""
     import jax, jax.numpy as jnp
     from jax.sharding import NamedSharding, PartitionSpec as P
     from repro.analysis.hlo import analyze_hlo
+    from repro.launch.mesh import make_host_mesh
 
-    mesh = jax.make_mesh((8,), ("d",))
+    mesh = make_host_mesh((8,), ("d",))
     x = jax.ShapeDtypeStruct((1024, 512), jnp.float32,
                              sharding=NamedSharding(mesh, P("d", None)))
     w = jax.ShapeDtypeStruct((512, 512), jnp.float32,

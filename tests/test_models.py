@@ -2,6 +2,8 @@
 instantiates its REDUCED config and runs one forward/train step on CPU,
 asserting output shapes and finiteness; plus decode-vs-prefill consistency
 (the serving path computes the same function as the parallel path)."""
+import dataclasses
+
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -156,3 +158,61 @@ def test_continuous_batching_vector_positions(models):
         ref, _ = m.decode_step(params, c1, nxt[r:r + 1])
         np.testing.assert_allclose(np.asarray(logits[r], np.float32),
                                    np.asarray(ref[0], np.float32), atol=1e-2)
+
+
+# ---------------------------------------------------------------------------
+# bf16 weights: Model.init casts inside one jitted program, so the device
+# never holds the float32 tree — and nothing downstream changes
+# ---------------------------------------------------------------------------
+
+def _as_param_dtype(cfg, dtype):
+    return dataclasses.replace(cfg, param_dtype=dtype)
+
+
+@pytest.mark.parametrize("arch", ["minitron-4b", "qwen2.5-32b",
+                                  "falcon-mamba-7b", "seamless-m4t-medium"])
+def test_bf16_init_equals_float32_init_cast(arch):
+    cfg = get_reduced(arch)
+    key = jax.random.key(4)
+    ref = strip(build_model(_as_param_dtype(cfg, "float32")).init(key))
+    ref = jax.tree.map(
+        lambda a: a.astype(jnp.bfloat16)
+        if jnp.issubdtype(a.dtype, jnp.floating) else a, ref)
+    got = strip(build_model(_as_param_dtype(cfg, "bfloat16")).init(key))
+    assert jax.tree.structure(got) == jax.tree.structure(ref)
+    for g, r in zip(jax.tree.leaves(got), jax.tree.leaves(ref)):
+        assert g.dtype == r.dtype
+        np.testing.assert_array_equal(np.asarray(g), np.asarray(r))
+
+
+def test_init_on_mesh_places_leaves_and_keeps_values():
+    from repro.launch.mesh import make_host_mesh
+    from repro.serve import serve_engine_rules
+    cfg = get_reduced("minitron-4b")
+    m = build_model(cfg)
+    mesh = make_host_mesh((1, 1))
+    got = strip(m.init(jax.random.key(2), mesh=mesh,
+                       rules=serve_engine_rules()))
+    ref = strip(m.init(jax.random.key(2)))
+    for g, r in zip(jax.tree.leaves(got), jax.tree.leaves(ref)):
+        assert g.sharding.mesh.shape == mesh.shape
+        np.testing.assert_array_equal(np.asarray(g), np.asarray(r))
+
+
+def test_bf16_weights_leave_greedy_streams_unchanged():
+    """Every layer casts weights to the (bf16) activation dtype where it
+    uses them, so bf16 weights serve exactly the float32 weights' tokens."""
+    from repro.workloads import DecodeEngine, ServeConfig
+    cfg = get_reduced("minitron-4b")
+    rng = np.random.default_rng(5)
+    prompts = [rng.integers(1, cfg.vocab_size, size=n) for n in (5, 11, 3)]
+
+    def serve(dtype):
+        m = build_model(_as_param_dtype(cfg, dtype))
+        eng = DecodeEngine(m, strip(m.init(jax.random.key(1))),
+                           ServeConfig(max_slots=2, max_len=48, eos_id=-1))
+        for p in prompts:
+            eng.submit(p, max_new_tokens=8)
+        return eng.run_to_completion(200)
+
+    assert serve("bfloat16") == serve("float32")

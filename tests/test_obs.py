@@ -275,9 +275,10 @@ def test_bounded_events_totals_survive_eviction():
     bugfix: a long-running fabric must not grow per recomposition, and
     `recompositions`/`retunes`/`recompose_seconds` must stay correct)."""
     import jax
+    from repro.launch.mesh import make_host_mesh
     from repro.serve import ComposedServer, ServeConfig, TenantSpec
 
-    mesh = jax.make_mesh((1, jax.device_count()), ("data", "model"))
+    mesh = make_host_mesh((1, jax.device_count()), ("data", "model"))
     srv = ComposedServer(
         mesh, [TenantSpec("a", "minitron-4b", reduced=True,
                           serve=ServeConfig(max_slots=2, max_len=32,
@@ -306,6 +307,7 @@ sys.path.insert(0, "src")
 import json
 import jax
 import numpy as np
+from repro.launch.mesh import make_host_mesh
 """
 
 
@@ -330,7 +332,7 @@ def test_streams_bit_identical_with_telemetry_on_off():
     sc = ServeConfig(max_slots=2, max_len=64, eos_id=-1)
 
     def run(telemetry):
-        mesh = jax.make_mesh((1, 8), ("data", "model"))
+        mesh = make_host_mesh((1, 8), ("data", "model"))
         srv = ComposedServer(mesh, [
             TenantSpec("a", "minitron-4b", serve=sc),
             TenantSpec("b", "falcon-mamba-7b", seed=1, serve=sc,

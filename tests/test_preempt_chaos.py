@@ -28,6 +28,7 @@ import dataclasses
 import json
 import jax
 import numpy as np
+from repro.launch.mesh import make_host_mesh
 """
 
 
@@ -43,7 +44,7 @@ _CHAOS_BODY = """
 from repro.launch.serve import MIXED_FLEET, _streams_digest
 from repro.serve import ComposedServer, ServeConfig, TenantSpec
 
-mesh = jax.make_mesh((1, 8), ("data", "model"))
+mesh = make_host_mesh((1, 8), ("data", "model"))
 serve = ServeConfig(max_slots=2, max_len=48, eos_id=-1, kv_page_rows=8,
                     use_kernels=__UK__)
 tenants = [TenantSpec(f"{w}-{arch}", arch, reduced=True, serve=serve,

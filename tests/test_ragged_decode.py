@@ -305,6 +305,7 @@ import dataclasses
 import json
 import jax
 import numpy as np
+from repro.launch.mesh import make_host_mesh
 """
 
 
@@ -329,7 +330,7 @@ def test_kernel_streams_invariant_tp_and_recomposition():
     from repro.serve import serve_engine_rules
     from repro.workloads import DecodeEngine, ServeConfig
 
-    mesh = jax.make_mesh((1, 8), ("data", "model"))
+    mesh = make_host_mesh((1, 8), ("data", "model"))
     comp = MeshComposer(mesh)
     cfg = dataclasses.replace(get_reduced("qwen2.5-32b"), dtype="float32")
     model = build_model(cfg)
@@ -370,3 +371,43 @@ def test_kernel_streams_invariant_tp_and_recomposition():
     """)
     assert res["n"] == 5
     assert res["k1"] and res["k2"] and res["p2"] and res["kdyn"]
+
+
+def test_kernel_under_a_mesh_runs_per_head_shard():
+    """Traced under a multi-device abstract mesh (as the decode engine
+    lowers for a TP sub-mesh), the kernel runs under shard_map: heads split
+    over the model axis when the KV heads divide it, every head on every
+    device otherwise — either way equal to the reference."""
+    res = _run("""
+    import jax.numpy as jnp
+    from jax.sharding import NamedSharding, PartitionSpec as P
+    from repro.kernels.ragged_decode import (ragged_decode_attention,
+                                             ragged_decode_attention_ref)
+
+    rng = np.random.default_rng(3)
+    out = {}
+    for n, hkv in ((2, 4), (4, 4), (4, 2)):
+        B, T, g, D = 4, 64, 3, 16
+        q = jnp.asarray(rng.normal(size=(B, 1, hkv * g, D)), jnp.float32)
+        k = jnp.asarray(rng.normal(size=(B, T, hkv, D)), jnp.float32)
+        v = jnp.asarray(rng.normal(size=(B, T, hkv, D)), jnp.float32)
+        lens = jnp.asarray([5, 64, 33, 1], jnp.int32)
+        live = jnp.asarray([1, 1, 0, 1], bool)
+        mesh = make_host_mesh((1, n))
+        ax = "model" if hkv % n == 0 else None
+        put = lambda x, s: jax.device_put(x, NamedSharding(mesh, s))
+        args = (put(q, P(None, None, ax, None)), put(k, P(None, None, ax, None)),
+                put(v, P(None, None, ax, None)), put(lens, P()), put(live, P()))
+        f = jax.jit(lambda q, k, v, n_, lv: ragged_decode_attention(
+            q, k, v, n_, live=lv, impl="interpret", bk=32))
+        with jax.sharding.use_abstract_mesh(mesh.abstract_mesh):
+            lowered = f.lower(*args)
+        got = lowered.compile()(*args)
+        ref = ragged_decode_attention_ref(q, k, v, lens, live=live)
+        out[f"{n}x{hkv}"] = float(jnp.abs(got - ref).max())
+        out[f"{n}x{hkv}_manual"] = "manual_computation" in lowered.as_text()
+    print(json.dumps(out))
+    """)
+    for case in ("2x4", "4x4", "4x2"):
+        assert res[case] <= 2e-5, (case, res)
+        assert res[f"{case}_manual"], (case, res)

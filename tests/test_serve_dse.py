@@ -306,14 +306,15 @@ def test_predicted_vs_measured_after_dse_retune():
         'sys.path.insert(0, "src")\n'
         "import json\n"
         "import jax\n"
-        "import numpy as np\n")
+        "import numpy as np\n"
+        "from repro.launch.mesh import make_host_mesh\n")
     body = textwrap.dedent("""
     import dataclasses
     from repro.serve.fabric import (AnalyticalPolicy, ComposedServer,
                                     TenantSpec)
     from repro.serve import ServeConfig
 
-    mesh = jax.make_mesh((1, 8), ("data", "model"))
+    mesh = make_host_mesh((1, 8), ("data", "model"))
     sc = ServeConfig(max_slots=2, max_len=48, eos_id=-1)
     tenants = [TenantSpec("a", "minitron-4b",
                           serve=dataclasses.replace(sc, slot_cap=4)),

@@ -245,9 +245,12 @@ def test_encdec_admission_backpressure_on_source_cache(seamless):
 
 def test_encoder_embeddings_bucket_invariant(seamless):
     """ROADMAP-flagged bugfix: the bidirectional encoder masks each row's
-    own bucket padding, so the same job's embedding is BIT-identical across
-    different bucket ladders (before the fix, the padded program shape
-    leaked into the numerics)."""
+    own bucket padding, so the same job's embedding does not depend on the
+    bucket ladder (before the fix, the padded program shape leaked into the
+    numerics).  Masked padding contributes exact zeros, but XLA may tile
+    the reductions of each program shape differently, so the embeddings
+    agree to float32 rounding (observed ~4e-7), pinned at the TP-degree
+    test's tolerance."""
     cfg, model, params = seamless
     job = np.arange(1, 6) % cfg.vocab_size
 
@@ -260,8 +263,9 @@ def test_encoder_embeddings_bucket_invariant(seamless):
         return eng.results()[rid]
 
     a, b, full = run((8,)), run((16,)), run(())
-    assert a == b == full, \
-        "bucket ladder changed a bidirectional embedding bit-for-bit"
+    for got in (a, b):
+        np.testing.assert_allclose(np.asarray(got), np.asarray(full),
+                                   rtol=1e-5, atol=1e-6)
 
 
 def test_encdec_forced_decode_matches_monolithic(seamless):
@@ -552,6 +556,7 @@ import dataclasses
 import json
 import jax
 import numpy as np
+from repro.launch.mesh import make_host_mesh
 """
 
 
@@ -574,7 +579,7 @@ def test_ssm_tp_and_recomposition_stream_invariance():
     from repro.serve import serve_engine_rules
     from repro.workloads import SSMEngine, ServeConfig
 
-    mesh = jax.make_mesh((1, 8), ("data", "model"))
+    mesh = make_host_mesh((1, 8), ("data", "model"))
     comp = MeshComposer(mesh)
     cfg = dataclasses.replace(get_reduced("falcon-mamba-7b"),
                               dtype="float32")
@@ -621,7 +626,7 @@ def test_encoder_embeddings_invariant_across_moves():
     from repro.serve import serve_engine_rules
     from repro.workloads import EncoderEngine, ServeConfig
 
-    mesh = jax.make_mesh((1, 8), ("data", "model"))
+    mesh = make_host_mesh((1, 8), ("data", "model"))
     comp = MeshComposer(mesh)
     cfg = dataclasses.replace(get_reduced("qwen2.5-32b"), dtype="float32")
     model = build_model(cfg)
@@ -669,7 +674,7 @@ def test_encdec_streams_invariant_across_recomposition():
     from repro.serve import serve_engine_rules
     from repro.workloads import EncDecEngine, ServeConfig
 
-    mesh = jax.make_mesh((1, 8), ("data", "model"))
+    mesh = make_host_mesh((1, 8), ("data", "model"))
     comp = MeshComposer(mesh)
     cfg = dataclasses.replace(get_reduced("seamless-m4t-medium"),
                               dtype="float32")
@@ -725,7 +730,7 @@ def test_live_reconfigure_stream_invariance():
     from repro.serve import serve_engine_rules
     from repro.workloads import DecodeEngine, SSMEngine, ServeConfig
 
-    mesh = jax.make_mesh((1, 8), ("data", "model"))
+    mesh = make_host_mesh((1, 8), ("data", "model"))
     comp = MeshComposer(mesh)
     rules = serve_engine_rules()
     out = {}
@@ -778,7 +783,7 @@ def test_mixed_fleet_end_to_end_with_live_class_moves():
                                     TenantSpec)
     from repro.workloads import ServeConfig
 
-    mesh = jax.make_mesh((1, 8), ("data", "model"))
+    mesh = make_host_mesh((1, 8), ("data", "model"))
     sc = ServeConfig(max_slots=2, max_len=48, eos_id=-1)
     s2t_sc = ServeConfig(max_slots=2, max_len=16, eos_id=-1, max_src_len=16,
                          len_buckets=(8,))
@@ -844,7 +849,7 @@ def test_speculative_runner_up_prewarm():
                                     TenantSpec)
     from repro.workloads import ServeConfig
 
-    mesh = jax.make_mesh((1, 8), ("data", "model"))
+    mesh = make_host_mesh((1, 8), ("data", "model"))
     sc = ServeConfig(max_slots=2, max_len=32, eos_id=-1)
     # min_gain pinned sky-high: every decide is a hysteresis tick, so the
     # test exercises exactly the idle-interval speculative path (at the
