@@ -6,7 +6,8 @@ a pre-bound label set, and an ``enabled`` flag.  Engines call
 ``obs.observe(...)`` / ``obs.span(...)`` unconditionally; when telemetry
 is disabled every call is a constant-time no-op (and ``span`` returns a
 shared null context manager), so token streams are bit-identical with
-telemetry on or off.
+telemetry on or off.  Each span is also a ``jax.profiler`` annotation
+named ``serve.<span>``, on the device trace's clock.
 
 Scoping rules:
 
@@ -24,9 +25,11 @@ import time
 from contextlib import contextmanager
 from typing import Any, Dict, Optional, Tuple
 
+from jax.profiler import TraceAnnotation
+
 from .metrics import (Counter, Gauge, Histogram, MetricsRegistry,
                       bucket_bounds, metric_key)
-from .tracing import NULL_SPAN, SpanTracer, trace_span
+from .tracing import NULL_SPAN, SpanTracer
 from .accounting import PredictionLedger
 
 __all__ = [
@@ -39,7 +42,6 @@ __all__ = [
     "Telemetry",
     "bucket_bounds",
     "metric_key",
-    "trace_span",
 ]
 
 
@@ -97,15 +99,16 @@ class Telemetry:
     @contextmanager
     def _timed(self, span_name: str, hist_name: Optional[str],
                args: Dict[str, Any]):
-        t0 = time.perf_counter()
-        try:
-            yield args
-        finally:
-            t1 = time.perf_counter()
-            self.tracer.record(span_name, t0, t1, args or None)
-            if hist_name is not None:
-                self.registry.histogram_at(
-                    hist_name, self.labels).observe(t1 - t0)
+        with TraceAnnotation(f"serve.{span_name}"):
+            t0 = time.perf_counter()
+            try:
+                yield args
+            finally:
+                t1 = time.perf_counter()
+                self.tracer.record(span_name, t0, t1, args or None)
+                if hist_name is not None:
+                    self.registry.histogram_at(
+                        hist_name, self.labels).observe(t1 - t0)
 
     def timed(self, span_name: str, hist_name: Optional[str] = None,
               **args: Any):

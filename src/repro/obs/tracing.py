@@ -3,11 +3,14 @@
 ``SpanTracer.span(...)`` is a context manager that records a complete
 ("ph": "X") trace event into a bounded ring buffer.  The record path is a
 ``perf_counter`` pair plus one deque append — cheap enough to leave on
-for every decode step.  Spans taken on background threads (e.g. the
-speculative-prewarm compile thread) land on their own ``tid`` row, which
-is exactly what makes compile/dispatch overlap visible in the Perfetto
-UI: open ``chrome://tracing`` or https://ui.perfetto.dev and load the
-file written by ``dump`` / ``ComposedServer.dump_trace``.
+for every decode step.  Each span is also a ``jax.profiler`` annotation
+named ``<cat>.<name>`` (``serve.decode_step``), so a profiler trace shows
+it on the host plane, on the device ops' clock; with no profiler session
+an annotation records nothing.  Spans taken on background
+threads (e.g. the speculative-prewarm compile thread) land on their own
+``tid`` row, which is exactly what makes compile/dispatch overlap visible
+in the Perfetto UI: open ``chrome://tracing`` or https://ui.perfetto.dev
+and load the file written by ``dump`` / ``ComposedServer.dump_trace``.
 """
 from __future__ import annotations
 
@@ -18,7 +21,9 @@ from collections import deque
 from contextlib import contextmanager
 from typing import Any, Dict, List, Optional
 
-__all__ = ["SpanTracer", "NULL_SPAN", "trace_span"]
+from jax.profiler import TraceAnnotation
+
+__all__ = ["SpanTracer", "NULL_SPAN"]
 
 
 class _NullSpan:
@@ -83,14 +88,16 @@ class SpanTracer:
         """Time a block and record it as a complete trace event.
 
         Yields the args dict so callers can attach results computed
-        inside the block (e.g. ``recompose`` fills in ``moved``)."""
+        inside the block (e.g. ``recompose`` fills in ``moved``).  The
+        profiler annotation carries the name only; args stay here."""
         payload: Dict[str, Any] = dict(args) if args else {}
-        t0 = time.perf_counter()
-        try:
-            yield payload
-        finally:
-            self.record(name, t0, time.perf_counter(), payload or None,
-                        cat=cat)
+        with TraceAnnotation(f"{cat}.{name}"):
+            t0 = time.perf_counter()
+            try:
+                yield payload
+            finally:
+                self.record(name, t0, time.perf_counter(), payload or None,
+                            cat=cat)
 
     def __len__(self) -> int:
         return len(self._events)
@@ -110,17 +117,3 @@ class SpanTracer:
 
     def clear(self) -> None:
         self._events.clear()
-
-
-# Module-level convenience used by ad-hoc scripts/tests: a process-wide
-# tracer so `with trace_span("phase"):` works without plumbing.
-_GLOBAL = SpanTracer()
-
-
-def trace_span(name: str, cat: str = "serve", **args: Any):
-    """Span context manager on the process-global tracer."""
-    return _GLOBAL.span(name, cat=cat, **args)
-
-
-def global_tracer() -> SpanTracer:
-    return _GLOBAL

@@ -38,7 +38,6 @@ from __future__ import annotations
 
 import contextlib
 import dataclasses
-import functools
 import itertools
 import os
 import threading
@@ -274,6 +273,9 @@ class DecodeEngine(EngineTelemetry):
                               if model.cfg.ssm is None else set())
 
         self._inflight: Optional[_Inflight] = None
+        # seconds the host has waited on harvests inside the current
+        # decode_step span; None outside one
+        self._step_wait: Optional[float] = None
         self._inject: Dict[int, int] = {}   # slot -> first token since last dispatch
         self._emit_buf: List[Tuple[int, int]] = []
 
@@ -865,10 +867,14 @@ class DecodeEngine(EngineTelemetry):
                 NamedSharding(mesh, P()),
                 plan.shardings(mesh, rules))
         # bounds bind as keywords so donate_argnums=(1,) keeps pointing at
-        # the cache positional
-        step = functools.partial(
-            self._decode_fn, **dict(zip(("kv_bound", "src_bound"), bounds)))
-        fn = jax.jit(step, donate_argnums=(1,), **kwargs)
+        # the cache positional; the function's name names the program
+        # (jit_decode_step) in profiler traces
+        bound_kw = dict(zip(("kv_bound", "src_bound"), bounds))
+
+        def decode_step(*args):
+            return self._decode_fn(*args, **bound_kw)
+
+        fn = jax.jit(decode_step, donate_argnums=(1,), **kwargs)
         # trace under the sub-mesh so the decode kernels wrap themselves in
         # shard_map (XLA cannot partition a Mosaic kernel on its own)
         ctx = (jax.sharding.use_abstract_mesh(mesh.abstract_mesh)
@@ -891,7 +897,11 @@ class DecodeEngine(EngineTelemetry):
             kwargs["out_shardings"] = (
                 NamedSharding(mesh, P()),
                 plan.shardings(mesh, rules))
-        fn = jax.jit(self._prefill_fn, donate_argnums=(1,), **kwargs)
+
+        def prefill(*args):             # the program's name: jit_prefill
+            return self._prefill_fn(*args)
+
+        fn = jax.jit(prefill, donate_argnums=(1,), **kwargs)
         return fn.lower(
             self._param_plan.avals(mesh, rules),
             plan.avals(mesh, rules),
@@ -1142,12 +1152,19 @@ class DecodeEngine(EngineTelemetry):
             # span + histogram around the dispatch/harvest pair: the
             # harvest's device_get of the PREVIOUS dispatch is the existing
             # sync point the host-side timing rides on — no extra syncs,
-            # pipelining preserved
-            with self._obs.timed("decode_step", "decode_step_s"):
+            # pipelining preserved.  harvest_wait_s sums the device_get
+            # waits inside the span, one observation per step, so
+            # decode_step_s minus it is the host's own work
+            obs = self._obs
+            if obs.enabled:
+                self._step_wait = 0.0
+            with obs.timed("decode_step", "decode_step_s"):
                 self._step_dispatch()
+            if obs.enabled:
+                obs.observe("harvest_wait_s", self._step_wait)
+                self._step_wait = None
             out = self._drain_emitted()
         sanitize_check(self)
-        obs = self._obs
         if obs.enabled:
             obs.set_gauge("slot_utilization",
                           len(self._active) / max(self.cfg.max_slots, 1))
@@ -1173,9 +1190,17 @@ class DecodeEngine(EngineTelemetry):
                 inject_vals[slot] = self._inject[slot]
         prev = (self._inflight.nxt if self._inflight is not None
                 else np.zeros((B,), np.int32))
-        exe = self._decode_exec(self.mesh, self._decode_bounds())
-        nxt, self.cache = exe(self.params, self.cache, prev,
-                              inject_vals, inject_mask, live)
+        obs = self._obs
+        if obs.enabled and not self.model.cfg.attention_free:
+            # KV rows this dispatch attends over, against the rows the
+            # dense device cache holds (every slot at full capacity)
+            obs.observe("kv_live_rows", sum(
+                self._dec_len(r) for r in self._active.values()))
+            obs.observe("kv_reserved_rows", B * self.cfg.max_len)
+        with obs.span("dispatch"):
+            exe = self._decode_exec(self.mesh, self._decode_bounds())
+            nxt, self.cache = exe(self.params, self.cache, prev,
+                                  inject_vals, inject_mask, live)
         self._inject.clear()
 
         entries = []
@@ -1213,7 +1238,11 @@ class DecodeEngine(EngineTelemetry):
         if inf is None:
             return
         self._inflight = None
-        nxt = np.asarray(jax.device_get(inf.nxt))
+        with self._obs.span("harvest"):
+            t0 = time.perf_counter()
+            nxt = np.asarray(jax.device_get(inf.nxt))
+            if self._step_wait is not None:
+                self._step_wait += time.perf_counter() - t0
         for slot, req, finishing in inf.entries:
             tok = int(nxt[slot])
             req.out_tokens.append(tok)

@@ -237,6 +237,69 @@ def test_timed_records_span_and_histogram():
 
 
 # ---------------------------------------------------------------------------
+# spans on the profiler's clock: each span is also a TraceAnnotation
+# ---------------------------------------------------------------------------
+
+
+def _profiled_spans(body, tmp_path):
+    """Run ``body`` under a CPU ``jax.profiler`` trace and read back the
+    host events named ``serve.*`` / ``fabric.*`` as (start, end, name)."""
+    import jax
+    from jax.profiler import ProfileData
+
+    opts = jax.profiler.ProfileOptions()
+    opts.python_tracer_level = 0
+    jax.profiler.start_trace(str(tmp_path), profiler_options=opts)
+    try:
+        body()
+    finally:
+        jax.profiler.stop_trace()
+    (path,) = tmp_path.glob("**/*.xplane.pb")
+    prof = ProfileData.from_file(str(path))
+    return sorted((e.start_ns, e.start_ns + e.duration_ns, e.name)
+                  for plane in prof.planes for line in plane.lines
+                  for e in line.events
+                  if e.name.startswith(("serve.", "fabric.")))
+
+
+def test_spans_are_profiler_annotations_nested_as_taken(tmp_path):
+    obs = Telemetry().scoped(tenant="a")
+
+    def body():
+        with obs.timed("decode_step", "decode_step_s"):
+            with obs.span("dispatch", n=2):
+                pass
+            with obs.timed("harvest"):
+                pass
+        with obs.tracer.span("move", cat="fabric"):
+            pass
+
+    ev = _profiled_spans(body, tmp_path)
+    # the annotation is named by category and span alone: args stay in
+    # the ring buffer
+    assert [n for _, _, n in ev] == ["serve.decode_step", "serve.dispatch",
+                                     "serve.harvest", "fabric.move"]
+    (s0, s1, _), dispatch, harvest, (m0, _, _) = ev
+    for a, b, _ in (dispatch, harvest):
+        assert s0 <= a <= b <= s1
+    assert dispatch[1] <= harvest[0] and s1 <= m0
+    assert [(e["name"], e.get("args")) for e in obs.tracer.events()] == [
+        ("decode_step", None), ("dispatch", {"n": 2}), ("harvest", None),
+        ("move", None)]
+
+
+def test_disabled_telemetry_writes_no_profiler_annotation(tmp_path):
+    obs = Telemetry.off()
+
+    def body():
+        with obs.timed("decode_step", "decode_step_s"):
+            with obs.span("harvest"):
+                pass
+
+    assert _profiled_spans(body, tmp_path) == []
+
+
+# ---------------------------------------------------------------------------
 # predicted-vs-measured ledger
 # ---------------------------------------------------------------------------
 

@@ -9,6 +9,7 @@ from repro.configs import get_reduced
 from repro.core.arena import AllocationError
 from repro.distribution import strip
 from repro.models import build_model
+from repro.obs import Telemetry
 from repro.serve import ExecutableCache, ServeConfig, ServeEngine
 
 import jax
@@ -168,3 +169,56 @@ def test_engine_reuses_decode_executable(qwen):
     builds = eng.compile_builds
     eng.run_to_completion(200)
     assert eng.compile_builds == builds
+
+
+# ---------------------------------------------------------------------------
+# decode-step telemetry: harvest waits, KV rows, program names
+# ---------------------------------------------------------------------------
+
+def test_step_histograms_one_observation_per_decode_step(qwen):
+    """harvest_wait_s, kv_live_rows and kv_reserved_rows are observed once
+    per decode step; harvests outside a step (snapshot, results, an idle
+    step) add none; kv_live_rows sums the KV rows each dispatch attends."""
+    cfg, model, params = qwen
+    obs = Telemetry()
+    eng = ServeEngine(model, params, ServeConfig(
+        max_slots=3, max_len=48, eos_id=-1, prefill_bucket=8), obs=obs)
+    rng = np.random.default_rng(5)
+    prompts = [rng.integers(1, cfg.vocab_size, size=int(n))
+               for n in rng.integers(4, 14, size=5)]
+    new = 6
+    for p in prompts:
+        eng.submit(p, max_new_tokens=new)
+    reg = obs.registry
+    names = ("decode_step_s", "harvest_wait_s", "kv_live_rows",
+             "kv_reserved_rows")
+    steps = 0
+    while eng.has_work:
+        eng.step()
+        eng.snapshot()              # harvests the in-flight dispatch
+        eng.results()
+        steps += 1
+        assert steps < 200
+    eng.step()                      # idle
+    counts = {n: reg.histogram(n).count for n in names}
+    assert counts["decode_step_s"] > 0
+    assert len(set(counts.values())) == 1, counts
+    # the first token comes from the prefill; each later one from a
+    # dispatch over prompt + k rows, k = 1 .. new - 1
+    want = sum(len(p) + k for p in prompts for k in range(1, new))
+    assert reg.histogram("kv_live_rows").sum == want
+    assert reg.histogram("kv_reserved_rows").sum == \
+        counts["kv_reserved_rows"] * 3 * 48
+    assert 0.0 <= reg.histogram("harvest_wait_s").sum <= \
+        reg.histogram("decode_step_s").sum
+
+
+def test_programs_are_named_for_traces(qwen):
+    """The decode and prefill programs show in a profiler trace as
+    jit_decode_step and jit_prefill."""
+    cfg, model, params = qwen
+    eng = _serve(model, params)
+    decode = eng._decode_exec(eng.mesh, eng._decode_bounds())
+    prefill = eng._prefill_exec(eng.mesh, 8)
+    assert decode.as_text().startswith("HloModule jit_decode_step,")
+    assert prefill.as_text().startswith("HloModule jit_prefill,")
