@@ -121,7 +121,7 @@ def gqa_prefill(p, cfg: ModelConfig, x, positions, cache, *, is_global=None,
 
 
 def gqa_step(p, cfg: ModelConfig, x1, cache, pos, *, is_global=None,
-             use_kernels=False, kv_bound=None, live=None):
+             use_kernels=False, kv_bound=None, live=None, layer=None):
     """Decode one token.  x1: (B, 1, d); pos: int32 (B,) per-row positions
     (continuous batching) or scalar.
 
@@ -129,21 +129,26 @@ def gqa_step(p, cfg: ModelConfig, x1, cache, pos, *, is_global=None,
     bounded to ``kv_bound`` rows (a static bound >= every live row's
     ``pos + 1``, threaded by the engine) and ``live`` marks empty slots.
     Live rows stay bit-identical to the padded read; the full-size cache is
-    still written so retunes/migrations see the same state either way."""
+    still written so retunes/migrations see the same state either way.
+
+    layer: with an index, ``cache`` holds the whole (L, B, T, Hkv, D) layer
+    stack; the new row of every slot is written into layer ``layer`` and
+    that layer is read back, and the stack is returned (see ``scatter_kv``)."""
     B = x1.shape[0]
     pos = jnp.broadcast_to(jnp.asarray(pos, jnp.int32), (B,))
     positions = pos[:, None]
     q, k, v = _project_qkv(p, cfg, x1, positions)
-    ck = L.scatter_kv(cache["k"], k, pos)
-    cv = L.scatter_kv(cache["v"], v, pos)
+    ck = L.scatter_kv(cache["k"], k, pos, layer)
+    cv = L.scatter_kv(cache["v"], v, pos, layer)
     window = cfg.window_size if cfg.attn_type == "sliding" else 0
     if use_kernels:
-        kb = ck.shape[1] if kv_bound is None else kv_bound
         o = ragged_decode_attention(
-            q, ck[:, :kb], cv[:, :kb], pos + 1, window=window,
+            q, L.layer_of(ck, layer, kv_bound),
+            L.layer_of(cv, layer, kv_bound), pos + 1, window=window,
             is_global=is_global, logit_cap=cfg.logit_softcap, live=live)
     else:
-        o = L.decode_attention(q, ck, cv, pos + 1, window=window,
+        o = L.decode_attention(q, L.layer_of(ck, layer),
+                               L.layer_of(cv, layer), pos + 1, window=window,
                                is_global=is_global,
                                logit_cap=cfg.logit_softcap)
     y = jnp.einsum("bshk,hkd->bsd", o, p["wo"].astype(x1.dtype))
@@ -327,22 +332,23 @@ def mla_prefill(p, cfg: ModelConfig, x, positions, cache, *,
 
 
 def mla_step(p, cfg: ModelConfig, x1, cache, pos, *, use_kernels=False,
-             kv_bound=None):
+             kv_bound=None, layer=None):
     """Absorbed-matmul MLA decode: attends in the R-dim latent space.
     pos: int32 (B,) per-row positions or scalar.  With use_kernels, the
     latent read is bounded to ``kv_bound`` rows (bit-identical: the masked
-    softmax ignores the dropped zero-score suffix)."""
+    softmax ignores the dropped zero-score suffix).  layer: as in
+    ``gqa_step``, for stacked (L, B, T, ...) latent caches."""
     m = cfg.mla
     B = x1.shape[0]
     pos = jnp.broadcast_to(jnp.asarray(pos, jnp.int32), (B,))
     positions = pos[:, None]
     q_nope, q_rope = _mla_q(p, cfg, x1, positions)          # (B,1,H,Dn/Dr)
     ckv1, kr1 = _mla_latents(p, cfg, x1, positions)
-    cckv = L.scatter_kv(cache["ckv"], ckv1, pos)
-    ckr = L.scatter_kv(cache["krope"], kr1, pos)
-    att_ckv, att_kr = cckv, ckr
-    if use_kernels and kv_bound is not None:
-        att_ckv, att_kr = cckv[:, :kv_bound], ckr[:, :kv_bound]
+    cckv = L.scatter_kv(cache["ckv"], ckv1, pos, layer)
+    ckr = L.scatter_kv(cache["krope"], kr1, pos, layer)
+    rows = kv_bound if use_kernels else None
+    att_ckv = L.layer_of(cckv, layer, rows)
+    att_kr = L.layer_of(ckr, layer, rows)
     # absorb W_uk into q: (B,H,R)
     q_abs = jnp.einsum("bshk,rhk->bshr", q_nope, p["w_uk"].astype(x1.dtype))[:, 0]
     scale = 1.0 / jnp.sqrt(jnp.asarray(m.qk_nope_head_dim + m.qk_rope_head_dim,

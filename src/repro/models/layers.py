@@ -428,12 +428,40 @@ def decode_attention(q, k_cache, v_cache, cache_len, *, window: int = 0,
     return out[:, None].astype(q.dtype)
 
 
-def scatter_kv(cache, new, pos):
+def layer_of(cache, layer=None, rows=None):
+    """One layer's cache: ``cache`` itself, or with ``layer`` that entry of
+    a stacked (L, B, T, ...) cache.  rows: keep only the first ``rows`` of
+    T, read by the same one slice."""
+    if layer is None:
+        return cache if rows is None else cache[:, :rows]
+    size = list(cache.shape)
+    size[0] = 1
+    if rows is not None:
+        size[2] = rows
+    start = (layer,) + (0,) * (cache.ndim - 1)
+    return jax.lax.dynamic_slice(cache, start, size)[0]
+
+
+def scatter_kv(cache, new, pos, layer=None):
     """Write `new` (B, 1, ...) into `cache` (B, T, ...) at per-row positions
     `pos` (B,) — the continuous-batching cache update (vmapped DUS lowers to
-    an efficient scatter)."""
-    def one(c, n, p):
-        return jax.lax.dynamic_update_slice(
-            c, n.astype(c.dtype), (p,) + (0,) * (c.ndim - 1))
+    an efficient scatter).
 
-    return jax.vmap(one)(cache, new, pos)
+    With ``layer``, `cache` is a layer stack (L, B, T, ...) and only the rows
+    ``[layer, b, pos[b]]`` are written, by one scatter: under a scan carry
+    the stack is updated where it lies.  The slot index is an iota, so the
+    TPU compiler keeps the scatter native (one with the slot axis as a
+    batching dimension it expands into a loop over slots); heads sharded
+    over a model axis partition it with no collective, and slots sharded
+    over a data axis gather only the indices and new rows, never the stack.
+    Indices clamp as DUS's do: a row past the end lands on row T-1."""
+    if layer is None:
+        def one(c, n, p):
+            return jax.lax.dynamic_update_slice(
+                c, n.astype(c.dtype), (p,) + (0,) * (c.ndim - 1))
+
+        return jax.vmap(one)(cache, new, pos)
+    slots = jnp.arange(new.shape[0])
+    return cache.at[layer, slots, pos].set(
+        new[:, 0].astype(cache.dtype), mode="clip", indices_are_sorted=True,
+        unique_indices=True)

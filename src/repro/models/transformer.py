@@ -9,7 +9,9 @@ attention layers) ride through the scan as boolean flag arrays.
 All stacks scan over layers (bounded HLO, fast compile for 88-layer models)
 and optionally remat the layer body (cfg.remat).
 
-Caches are stacked (L, ...) pytrees threaded through the same scan.
+Caches are stacked (L, ...) pytrees.  Prefill threads them through the
+scan as xs/ys; the decode step carries the stack and writes one row per
+slot per layer into it.
 """
 from __future__ import annotations
 
@@ -184,38 +186,56 @@ def _layer_prefill(p, cfg, x, positions, cache, *, is_global=None,
 
 def _layer_step(p, cfg, x1, cache, pos, *, is_global=None, src_len=None,
                 moe_dispatch="einsum", use_kernels=False, kv_bound=None,
-                src_bound=None, live=None):
+                src_bound=None, live=None, layer=None):
+    """One decoder layer on one token.  cache: this layer's cache, or with
+    ``layer`` (an int32 index) the whole layer stack: attention writes its
+    new rows into layer ``layer`` where the stack lies, the SSM's state is
+    replaced in that layer, cross-attention KV is only read, and the stack
+    is returned."""
     h = L.apply_norm(cfg.norm, p["ln1"], x1, cfg.norm_eps)
     new_cache = dict(cache)
+
+    def ssm(h):
+        y, st = S.mamba_step(p["ssm"], cfg, h,
+                             jax.tree.map(lambda a: L.layer_of(a, layer),
+                                          cache["ssm"]),
+                             use_kernels=use_kernels, live=live)
+        if layer is not None:
+            st = jax.tree.map(
+                lambda a, n: jax.lax.dynamic_update_index_in_dim(
+                    a, n.astype(a.dtype), layer, 0), cache["ssm"], st)
+        return y, st
+
     if cfg.hybrid_parallel:
         a, new_cache["attn"] = A.gqa_step(p["attn"], cfg, h, cache["attn"],
                                           pos, is_global=is_global,
                                           use_kernels=use_kernels,
-                                          kv_bound=kv_bound, live=live)
-        s, new_cache["ssm"] = S.mamba_step(p["ssm"], cfg, h, cache["ssm"],
-                                           use_kernels=use_kernels, live=live)
+                                          kv_bound=kv_bound, live=live,
+                                          layer=layer)
+        s, new_cache["ssm"] = ssm(h)
         a = L.apply_norm("rmsnorm", p["attn_out_norm"], a, cfg.norm_eps)
         s = L.apply_norm("rmsnorm", p["ssm_out_norm"], s, cfg.norm_eps)
         x1 = x1 + 0.5 * (a + s)
     elif cfg.ssm is not None:
-        y, new_cache["ssm"] = S.mamba_step(p["ssm"], cfg, h, cache["ssm"],
-                                           use_kernels=use_kernels, live=live)
+        y, new_cache["ssm"] = ssm(h)
         x1 = x1 + y
     elif cfg.mla is not None:
         y, new_cache["attn"] = A.mla_step(p["attn"], cfg, h, cache["attn"],
                                           pos, use_kernels=use_kernels,
-                                          kv_bound=kv_bound)
+                                          kv_bound=kv_bound, layer=layer)
         x1 = x1 + y
     else:
         y, new_cache["attn"] = A.gqa_step(p["attn"], cfg, h, cache["attn"],
                                           pos, is_global=is_global,
                                           use_kernels=use_kernels,
-                                          kv_bound=kv_bound, live=live)
+                                          kv_bound=kv_bound, live=live,
+                                          layer=layer)
         x1 = x1 + y
     if "cross" in p:
         hc = L.apply_norm(cfg.norm, p["ln_cross"], x1, cfg.norm_eps)
-        x1 = x1 + A.cross_step(p["cross"], cfg, hc, cache["cross_k"],
-                               cache["cross_v"], src_len,
+        x1 = x1 + A.cross_step(p["cross"], cfg, hc,
+                               L.layer_of(cache["cross_k"], layer),
+                               L.layer_of(cache["cross_v"], layer), src_len,
                                use_kernels=use_kernels, src_bound=src_bound,
                                live=live)
     if "moe" in p:
@@ -407,16 +427,21 @@ def decoder_step(params, cfg: ModelConfig, x1, cache, *, src_len=None,
         new_pro.append(nc)
     flags = _global_flags(cfg, n_pro, n_scan)
 
-    def body(h, xs):
-        lp, lc, is_global = xs
-        h, nc = _layer_step(lp, cfg, h, lc, pos, is_global=is_global,
-                            src_len=src_len, moe_dispatch=moe_dispatch,
-                            use_kernels=use_kernels, kv_bound=kv_bound,
-                            src_bound=src_bound, live=live)
-        return h, nc
+    # the stacked cache rides in the carry, not in xs/ys: each layer writes
+    # only its new rows into the stack where it lies (XLA slices an xs/ys
+    # cache out, restacks it whole and copies it back, every step)
+    def body(carry, xs):
+        h, stack = carry
+        lp, is_global, layer = xs
+        h, stack = _layer_step(lp, cfg, h, stack, pos, is_global=is_global,
+                               src_len=src_len, moe_dispatch=moe_dispatch,
+                               use_kernels=use_kernels, kv_bound=kv_bound,
+                               src_bound=src_bound, live=live, layer=layer)
+        return (h, stack), None
 
-    x1, new_scanned = jax.lax.scan(body, x1, (params["scanned"],
-                                              cache["scanned"], flags))
+    (x1, new_scanned), _ = jax.lax.scan(
+        body, (x1, cache["scanned"]),
+        (params["scanned"], flags, jnp.arange(n_scan, dtype=jnp.int32)))
     new_cache = {"prologue": new_pro, "scanned": new_scanned, "pos": pos + 1}
     return x1, new_cache
 

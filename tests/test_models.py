@@ -3,6 +3,7 @@ instantiates its REDUCED config and runs one forward/train step on CPU,
 asserting output shapes and finiteness; plus decode-vs-prefill consistency
 (the serving path computes the same function as the parallel path)."""
 import dataclasses
+import functools
 
 import jax
 import jax.numpy as jnp
@@ -216,3 +217,90 @@ def test_bf16_weights_leave_greedy_streams_unchanged():
         return eng.run_to_completion(200)
 
     assert serve("bfloat16") == serve("float32")
+
+
+# ---------------------------------------------------------------------------
+# the decode step carries the stacked cache through its layer loop and writes
+# one row per slot per layer: the same computation as stepping each layer on
+# its own slice, bit for bit
+# ---------------------------------------------------------------------------
+
+def _per_layer_decoder_step(params, cfg, x1, cache, **kw):
+    """Reference ``decoder_step``: a Python loop over the layers, each
+    ``_layer_step`` on that layer's own sliced cache, restacked."""
+    from repro.models import transformer as T
+    pos = cache["pos"]
+    n_pro, n_scan = T._prologue_plan(cfg)
+    pro = []
+    for i, (lp, lc) in enumerate(zip(params["prologue"], cache["prologue"])):
+        x1, nc = T._layer_step(
+            lp, cfg, x1, lc, pos,
+            is_global=jnp.asarray(i in cfg.global_attn_layers), **kw)
+        pro.append(nc)
+    layers = []
+    for l in range(n_scan):
+        lp = jax.tree.map(lambda a: a[l], params["scanned"])
+        lc = jax.tree.map(lambda a: a[l], cache["scanned"])
+        x1, nc = T._layer_step(
+            lp, cfg, x1, lc, pos,
+            is_global=jnp.asarray(n_pro + l in cfg.global_attn_layers), **kw)
+        layers.append(nc)
+    scanned = jax.tree.map(lambda *a: jnp.stack(a), *layers)
+    return x1, {"prologue": pro, "scanned": scanned, "pos": pos + 1}
+
+
+def _bits(a):
+    a = np.asarray(a)
+    return a.view(f"u{a.dtype.itemsize}")
+
+
+@pytest.mark.parametrize("kv_bound", [None, 32, 16],
+                         ids=["padded", "ragged-full", "ragged-bounded"])
+@pytest.mark.parametrize("arch", ["minitron-4b", "hymba-1.5b",
+                                  "deepseek-v2-lite-16b", "falcon-mamba-7b",
+                                  "seamless-m4t-medium"])
+def test_decode_step_carried_cache_matches_per_layer(models, arch, kv_bound,
+                                                     monkeypatch):
+    """Dense GQA, sliding window + SSM (hybrid), MLA with a dense prologue
+    layer, SSM, and enc-dec cross-KV; the padded path and the ragged one
+    with a full and a bounded KV read.  Slot 2 sits at max_len - 1 and slot
+    3 past the end (its write clamps onto the last row); dead slots are
+    those the bound leaves out, and slot 3 always."""
+    from repro.models import transformer as T
+    cfg, m, params = models(arch)
+    nb, max_len, src = 4, 32, 16
+    cache = strip(m.init_cache(nb, max_len,
+                               src_len=src if cfg.is_encdec else 0))
+    rng = np.random.default_rng(7)
+    cache = jax.tree.map(
+        lambda a: jnp.asarray(rng.normal(size=a.shape), a.dtype)
+        if jnp.issubdtype(a.dtype, jnp.floating) else a, cache)
+    pos = np.array([5, 13, max_len - 1, max_len + 6], np.int32)
+    cache["pos"] = jnp.asarray(pos)
+    if cfg.is_encdec:
+        cache["src_len"] = jnp.asarray([src, 3, 7, src], jnp.int32)
+    bound = max_len if kv_bound is None else kv_bound
+    live = jnp.asarray((pos + 1 <= bound) & (np.arange(nb) != 3))
+    tokens = jnp.asarray([[3], [11], [7], [1]], jnp.int32)
+
+    def run():
+        step = functools.partial(m.decode_step,
+                                 use_kernels=kv_bound is not None,
+                                 kv_bound=kv_bound)
+        # no excess precision: XLA may otherwise skip a bf16 rounding in one
+        # program's fusions and not in the other's
+        fn = jax.jit(lambda p, c, t, lv: step(p, c, t, live_mask=lv))
+        exe = fn.lower(params, cache, tokens, live).compile(
+            compiler_options={"xla_allow_excess_precision": False})
+        return jax.block_until_ready(exe(params, cache, tokens, live))
+
+    got_logits, got_cache = run()
+    monkeypatch.setattr(T, "decoder_step", _per_layer_decoder_step)
+    ref_logits, ref_cache = run()
+    np.testing.assert_array_equal(_bits(got_logits), _bits(ref_logits))
+    assert jax.tree.structure(got_cache) == jax.tree.structure(ref_cache)
+    for (path, g), r in zip(jax.tree_util.tree_leaves_with_path(got_cache),
+                            jax.tree.leaves(ref_cache)):
+        assert g.shape == r.shape and g.dtype == r.dtype, path
+        np.testing.assert_array_equal(_bits(g), _bits(r),
+                                      err_msg=jax.tree_util.keystr(path))

@@ -5,7 +5,9 @@ attached) ``v5e:2x2`` topology, which is what the chip's compiler would
 accept or refuse.  Interpret-mode tests cannot see Mosaic's tiling rules or
 its VMEM limit; these can.  A compile takes a second or two.
 """
+import dataclasses
 import os
+import re
 
 import numpy as np
 import pytest
@@ -18,6 +20,7 @@ from jax.sharding import (AxisType, Mesh, NamedSharding, PartitionSpec as P,
 from repro.configs import get_config
 from repro.kernels.mamba_scan import mamba_step_kernel
 from repro.kernels.ragged_decode import ops
+from repro.models import build_model
 from repro.models.ssm import dims as ssm_dims
 
 
@@ -96,6 +99,51 @@ def test_ragged_decode_kernel_compiles_tensor_parallel(topo, monkeypatch):
     text = lowered.compile().as_text()
     assert "tpu_custom_call" in text
     assert "all-gather" not in text
+
+
+def test_decode_step_updates_stacked_kv_cache_in_place(topo, monkeypatch):
+    """DecodeEngine's decode program at minitron-4b's widths, cut to 2
+    layers, with its serving cache (12 slots x 2048, Hkv 8, D 128, bf16) and
+    a KV bound of 1024: the layer loop writes one row per slot per layer
+    into the stacked cache where it lies.  No copy and no
+    dynamic-update-slice the size of the stack or of one layer's slice, no
+    temporary that large, and the donated stacks alias the outputs."""
+    from repro.serve import serve_engine_rules
+    from repro.workloads import DecodeEngine, ServeConfig
+    monkeypatch.setattr(ops, "_on_tpu", lambda: True)
+    cfg = dataclasses.replace(get_config("minitron-4b"), num_layers=2)
+    model = build_model(cfg)
+    params = jax.eval_shape(model.init, jax.random.key(0))
+    eng = DecodeEngine(model, params,
+                       ServeConfig(max_slots=12, max_len=2048, eos_id=-1,
+                                   use_kernels=True),
+                       rules=serve_engine_rules())
+    mesh = Mesh(np.array(topo.devices[:1]).reshape(1, 1), ("data", "model"),
+                axis_types=(AxisType.Auto,) * 2)
+    compiled = eng._build_decode(mesh, bounds=(1024,))
+    text = compiled.as_text()
+    assert "tpu_custom_call" in text
+    stack = "bf16[2,12,2048,8,128]"
+    layer = "bf16[12,2048,8,128]"
+    moves = [(name, shape) for name, shape, op in re.findall(
+                 r"%(\S+) = (bf16\[[\d,]+\])\S* ([\w-]+)\(", text)
+             if shape in (stack, layer)
+             and any(w in name or w in op
+                     for w in ("copy", "dynamic-update-slice"))]
+    assert not moves, moves
+    layer_bytes = 12 * 2048 * 8 * 128 * 2
+    mem = compiled.memory_analysis()
+    assert mem.temp_size_in_bytes < layer_bytes, mem
+    # the K and V stacks are ENTRY parameters, each aliased to an output
+    entry = text[text.index("\nENTRY "):]
+    entry = entry[:entry.index("\n}")]
+    stack_params = re.findall(
+        re.escape(stack) + r"\S* parameter\((\d+)\)", entry)
+    assert len(stack_params) == 2, stack_params
+    header = text[:text.index("\n")]
+    aliased = set(re.findall(r"\{[\d,]*\}: \((\d+), ", header))
+    assert set(stack_params) <= aliased, (stack_params, header[:300])
+    assert mem.alias_size_in_bytes >= 2 * 2 * layer_bytes, mem
 
 
 def _mamba_avals(d_model, d_in, dt_rank, n, w, sharding, B=8):
