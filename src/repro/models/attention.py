@@ -50,10 +50,53 @@ def gqa_init(rng, cfg: ModelConfig):
     return p
 
 
+QKV = ("wq", "wk", "wv")
+
+
+def project_heads(x, w):
+    """x (B, S, d) times a Q/K/V weight cast to x's dtype -> (B, S, H, D).
+
+    The weight is (..., d, H, D) as ``gqa_init`` draws it, or heads-major
+    (..., H, d, D) as the serving engines hold it (``heads_major``); its
+    shape tells which.  The TPU compiler lowers this projection as a
+    convolution windowed over heads that reads the weight heads-major: a
+    (d, H, D) layer of the stacked params is staged into VMEM and relaid
+    out before its dot, a heads-major one streams from HBM into it."""
+    spec = "bsd,dhk->bshk" if w.shape[-3] == x.shape[-1] else "bsd,hdk->bshk"
+    return jnp.einsum(spec, x, w.astype(x.dtype))
+
+
+_swap_heads = jax.jit(lambda a: jnp.swapaxes(a, -3, -2))
+
+
+def heads_major(params, d_model: int):
+    """``params`` (as ``Model.init`` returns it: Annotated or stripped,
+    concrete or abstract) with every GQA self- and cross-attention
+    ``wq``/``wk``/``wv`` moved from (..., d, H, D) to (..., H, d, D), its
+    logical axes permuted with it, so sharding plans keep the heads on the
+    model axis.  One leaf at a time; leaves already heads-major and every
+    other leaf (MLA, ``wo``, biases, norms) come back as they are."""
+    def leaf(path, x):
+        if getattr(path[-1], "key", None) not in QKV:
+            return x
+        v = x.value if isinstance(x, Annotated) else x
+        if v.shape[-3] != d_model:
+            return x
+        v = (jax.eval_shape(_swap_heads, v)
+             if isinstance(v, jax.ShapeDtypeStruct) else _swap_heads(v))
+        if not isinstance(x, Annotated):
+            return v
+        *lead, a, b, last = x.logical
+        return Annotated(v, (*lead, b, a, last))
+
+    return jax.tree_util.tree_map_with_path(
+        leaf, params, is_leaf=lambda x: isinstance(x, Annotated))
+
+
 def _project_qkv(p, cfg: ModelConfig, x, positions):
-    q = jnp.einsum("bsd,dhk->bshk", x, p["wq"].astype(x.dtype))
-    k = jnp.einsum("bsd,dhk->bshk", x, p["wk"].astype(x.dtype))
-    v = jnp.einsum("bsd,dhk->bshk", x, p["wv"].astype(x.dtype))
+    q = project_heads(x, p["wq"])
+    k = project_heads(x, p["wk"])
+    v = project_heads(x, p["wv"])
     if cfg.qkv_bias:
         q = q + p["bq"].astype(x.dtype)
         k = k + p["bk"].astype(x.dtype)
@@ -174,9 +217,9 @@ def cross_fwd(p, cfg: ModelConfig, x, enc_out, enc_positions, src_len=None):
     ``cross_step``'s masked ``decode_attention`` read.  None keeps the
     unmasked training path (exact-length encoder outputs).
     """
-    q = jnp.einsum("bsd,dhk->bshk", x, p["wq"].astype(x.dtype))
-    k = jnp.einsum("bsd,dhk->bshk", enc_out, p["wk"].astype(x.dtype))
-    v = jnp.einsum("bsd,dhk->bshk", enc_out, p["wv"].astype(x.dtype))
+    q = project_heads(x, p["wq"])
+    k = project_heads(enc_out, p["wk"])
+    v = project_heads(enc_out, p["wv"])
     if cfg.qkv_bias:
         q = q + p["bq"].astype(x.dtype)
         k = k + p["bk"].astype(x.dtype)
@@ -202,8 +245,8 @@ def cross_fwd(p, cfg: ModelConfig, x, enc_out, enc_positions, src_len=None):
 
 
 def cross_kv(p, cfg: ModelConfig, enc_out):
-    k = jnp.einsum("bsd,dhk->bshk", enc_out, p["wk"].astype(enc_out.dtype))
-    v = jnp.einsum("bsd,dhk->bshk", enc_out, p["wv"].astype(enc_out.dtype))
+    k = project_heads(enc_out, p["wk"])
+    v = project_heads(enc_out, p["wv"])
     if cfg.qkv_bias:
         k = k + p["bk"].astype(enc_out.dtype)
         v = v + p["bv"].astype(enc_out.dtype)
@@ -212,7 +255,7 @@ def cross_kv(p, cfg: ModelConfig, enc_out):
 
 def cross_step(p, cfg: ModelConfig, x1, ck, cv, src_len, *,
                use_kernels=False, src_bound=None, live=None):
-    q = jnp.einsum("bsd,dhk->bshk", x1, p["wq"].astype(x1.dtype))
+    q = project_heads(x1, p["wq"])
     if cfg.qkv_bias:
         q = q + p["bq"].astype(x1.dtype)
     if use_kernels:

@@ -16,6 +16,7 @@ import jax.numpy as jnp
 from repro.configs.base import ModelConfig, ShapeCell
 from repro.distribution.partitioning import (Annotated, ShardingPlan,
                                              ShardingRules)
+from repro.models import attention as A
 from repro.models import layers as L
 from repro.models import transformer as T
 
@@ -53,6 +54,14 @@ class Model:
         out = plan.shardings(mesh, rules or ShardingRules(rules={}))
         return jax.jit(_init_tree, static_argnums=0,
                        out_shardings=out)(self.cfg, rng)
+
+    def heads_major(self, params) -> PyTree:
+        """The serving layout of ``params``: every GQA ``wq``/``wk``/``wv``
+        heads-major, (..., H, d, D), so the decode and prefill programs
+        read each layer's slice of the stacked weight straight into its
+        projection (see ``attention.heads_major``).  Every method here
+        takes either layout."""
+        return A.heads_major(params, self.cfg.d_model)
 
     # ------------------------------------------------------------------
     def _head(self, params):

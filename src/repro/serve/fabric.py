@@ -1152,10 +1152,13 @@ class ComposedServer:
                    else get_config(spec.arch))
             model = build_model(cfg)
             # annotated (TP plans need the logical specs), and made on the
-            # tenant's own sub-mesh: no full copy ever lands on device 0
-            params = model.init(jax.random.key(spec.seed),
-                                mesh=self.subs[spec.name].mesh,
-                                rules=self.rules)
+            # tenant's own sub-mesh: no full copy ever lands on device 0.
+            # Put in the engines' heads-major layout here, where no other
+            # reference holds the drawn tree: its Q/K/V leaves are freed
+            # before the engine allocates its cache
+            params = model.heads_major(model.init(
+                jax.random.key(spec.seed), mesh=self.subs[spec.name].mesh,
+                rules=self.rules))
             wclass = (workload_class_of(cfg) if spec.workload == "auto"
                       else spec.workload)
             self.cfgs[spec.name] = cfg

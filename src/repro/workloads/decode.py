@@ -234,6 +234,9 @@ class DecodeEngine(EngineTelemetry):
         self._next_rid = 0
         self._free_slots = list(range(cfg.max_slots))
 
+        # the programs read Q/K/V weights heads-major (Model.heads_major);
+        # converted here, before the pooled cache exists
+        params = model.heads_major(params)
         # sharding plans: treedef + per-leaf (shape, dtype, logical spec),
         # captured before strip() so any composed sub-mesh's shardings and
         # lowering avals can be derived without re-annotating live state

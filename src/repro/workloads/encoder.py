@@ -88,6 +88,7 @@ class EncoderEngine(EngineTelemetry):
         self._obs = obs if obs is not None else Telemetry()
         self._rules_eff = rules or part.ShardingRules(rules={})
         self.reshard_count = 0
+        params = model.heads_major(params)    # as DecodeEngine holds them
         self._param_plan = part.ShardingPlan.of(params)
         self.params = part.strip(params)
         if rules is not None and not self._param_plan.annotated:

@@ -237,7 +237,9 @@ def test_tp_decode_equivalence_across_degrees():
     sub-meshes must emit identical token streams, including across a
     mid-stream reshard_to() that changes the TP degree (satellite +
     tentpole acceptance: sharded decode is an implementation detail, never
-    a numerics change a user can observe)."""
+    a numerics change a user can observe).  The engine holds wq/wk/wv
+    heads-major, (L, H, d, D), from construction on and across every
+    move, with the heads on the model axis wherever they divide it."""
     res = _run("""
     import dataclasses
     from repro.configs import get_reduced
@@ -256,16 +258,34 @@ def test_tp_decode_equivalence_across_degrees():
     prompts = [rng.integers(1, cfg.vocab_size,
                             size=int(rng.integers(4, 12))) for _ in range(3)]
 
+    layouts = []
+
+    def check_layout(eng, sharded):
+        attn = eng.params["decoder"]["scanned"]["attn"]
+        width = eng.mesh.shape["model"]
+        for name, h in (("wq", cfg.num_heads), ("wk", cfg.num_kv_heads),
+                        ("wv", cfg.num_kv_heads)):
+            w = attn[name]
+            spec = tuple(w.sharding.spec) + (None,) * 4
+            heads = "model" if sharded and h % width == 0 else None
+            layouts.append(
+                (name, width, w.shape, spec[:4]) if
+                w.shape != (cfg.num_layers, h, cfg.d_model,
+                            cfg.resolved_head_dim)
+                or spec[:4] != (None, heads, None, None) else "ok")
+
     def run(tp, rules, script=None):
         eng = ServeEngine(model, params, sc,
                           mesh=comp.submesh(range(tp), f"tp{tp}"),
                           rules=rules)
+        check_layout(eng, rules is not None)
         for p in prompts:
             eng.submit(p, max_new_tokens=10)
         step = 0
         while eng.has_work:
             if script and step in script:
                 eng.reshard_to(comp.submesh(range(script[step]), "re"))
+                check_layout(eng, rules is not None)
             eng.step()
             step += 1
             assert step < 200
@@ -277,11 +297,14 @@ def test_tp_decode_equivalence_across_degrees():
     tp4 = run(4, rules)
     dyn = run(4, rules, {3: 2, 7: 8, 11: 4})     # shrink -> unify -> back
     print(json.dumps({"n": len(ref), "tp2": tp2 == ref, "tp4": tp4 == ref,
-                      "dyn": dyn == ref}))
+                      "dyn": dyn == ref,
+                      "layouts": [l for l in layouts if l != "ok"],
+                      "checked": len(layouts)}))
     """)
     assert res["n"] == 3
     assert res["tp2"] and res["tp4"], "TP decode diverged from replicated"
     assert res["dyn"], "mid-stream TP-degree change altered the stream"
+    assert res["checked"] == 3 * 7 and not res["layouts"], res["layouts"]
 
 
 def test_warm_recompose_skips_post_move_compile():

@@ -304,3 +304,86 @@ def test_decode_step_carried_cache_matches_per_layer(models, arch, kv_bound,
         assert g.shape == r.shape and g.dtype == r.dtype, path
         np.testing.assert_array_equal(_bits(g), _bits(r),
                                       err_msg=jax.tree_util.keystr(path))
+
+
+# ---------------------------------------------------------------------------
+# the serving engines hold Q/K/V weights heads-major (Model.heads_major); the
+# model reads either layout and computes the same bits
+# ---------------------------------------------------------------------------
+
+def _qkv_leaves(tree):
+    from repro.distribution import Annotated
+    from repro.models.attention import QKV
+    return {jax.tree_util.keystr(path): leaf
+            for path, leaf in jax.tree_util.tree_leaves_with_path(
+                tree, is_leaf=lambda x: isinstance(x, Annotated))
+            if getattr(path[-1], "key", None) in QKV}
+
+
+@pytest.mark.parametrize("arch", ["minitron-4b", "hymba-1.5b",
+                                  "seamless-m4t-medium", "qwen2.5-32b",
+                                  "chameleon-34b"])
+def test_heads_major_params_serve_the_same_bits(arch):
+    """Dense GQA, sliding window + SSM (hybrid), enc-dec cross attention,
+    QKV bias and QK norm: prefill then decode with the heads-major tree
+    gives the logits of every step and every cache leaf of the Model.init
+    tree, bit for bit.  Each Q/K/V leaf is the same values with its
+    (d, H, D) axes moved to (H, d, D), its logical axes with them; every
+    other leaf is untouched, and the conversion of an abstract tree, or of
+    a converted one, agrees."""
+    cfg = get_reduced(arch)
+    m = build_model(cfg)
+    ann = m.init(jax.random.key(0))
+    hm_ann = m.heads_major(ann)
+    qkv, hm_qkv = _qkv_leaves(ann), _qkv_leaves(hm_ann)
+    assert qkv.keys() == hm_qkv.keys()
+    n_cross = sum("cross" in k for k in qkv)
+    assert len(qkv) >= 3 and (n_cross == 3) == cfg.is_encdec, sorted(qkv)
+    for k, leaf in qkv.items():
+        got = hm_qkv[k]
+        assert leaf.value.shape[-3] == cfg.d_model, k
+        np.testing.assert_array_equal(
+            np.asarray(got.value), np.swapaxes(np.asarray(leaf.value), -3, -2))
+        *lead, d, h, last = leaf.logical
+        assert d == "embed" and h in ("heads", "kv_heads"), (k, leaf.logical)
+        assert tuple(got.logical) == (*lead, h, d, last), (k, got.logical)
+    rest = [l for l in jax.tree.leaves(ann) if all(
+        l is not q.value for q in qkv.values())]
+    hm_rest = [l for l in jax.tree.leaves(hm_ann) if all(
+        l is not q.value for q in hm_qkv.values())]
+    assert len(rest) == len(hm_rest)
+    assert all(a is b for a, b in zip(rest, hm_rest))
+    abstract = m.heads_major(jax.eval_shape(m.init, jax.random.key(0)))
+    assert jax.tree.map(lambda a: (a.shape, a.dtype), strip(abstract)) == \
+        jax.tree.map(lambda a: (a.shape, a.dtype), strip(hm_ann))
+    again = m.heads_major(hm_ann)
+    assert all(a is b for a, b in zip(jax.tree.leaves(again),
+                                      jax.tree.leaves(hm_ann)))
+
+    batch = _batch(cfg)
+    tokens, k = batch["tokens"], S // 2
+    sl = S if cfg.is_encdec else 0
+
+    def serve(params):
+        cache = strip(m.init_cache(B, 2 * S, src_len=sl))
+        logits, cache = m.prefill(params, {**batch, "tokens": tokens[:, :k]},
+                                  cache)
+        steps = [logits]
+        for i in range(k, S):
+            logits, cache = m.decode_step(params, cache, tokens[:, i:i + 1])
+            steps.append(logits)
+        return jnp.stack(steps), cache
+
+    def run(params):
+        exe = jax.jit(serve).lower(params).compile(
+            compiler_options={"xla_allow_excess_precision": False})
+        return jax.block_until_ready(exe(params))
+
+    ref_logits, ref_cache = run(strip(ann))
+    got_logits, got_cache = run(strip(hm_ann))
+    np.testing.assert_array_equal(_bits(got_logits), _bits(ref_logits))
+    assert jax.tree.structure(got_cache) == jax.tree.structure(ref_cache)
+    for (path, g), r in zip(jax.tree_util.tree_leaves_with_path(got_cache),
+                            jax.tree.leaves(ref_cache)):
+        np.testing.assert_array_equal(_bits(g), _bits(r),
+                                      err_msg=jax.tree_util.keystr(path))

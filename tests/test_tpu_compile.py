@@ -101,16 +101,12 @@ def test_ragged_decode_kernel_compiles_tensor_parallel(topo, monkeypatch):
     assert "all-gather" not in text
 
 
-def test_decode_step_updates_stacked_kv_cache_in_place(topo, monkeypatch):
-    """DecodeEngine's decode program at minitron-4b's widths, cut to 2
-    layers, with its serving cache (12 slots x 2048, Hkv 8, D 128, bf16) and
-    a KV bound of 1024: the layer loop writes one row per slot per layer
-    into the stacked cache where it lies.  No copy and no
-    dynamic-update-slice the size of the stack or of one layer's slice, no
-    temporary that large, and the donated stacks alias the outputs."""
+def _minitron_engine(topo):
+    """DecodeEngine at minitron-4b's widths, cut to 2 layers, with its
+    serving cache (12 slots x 2048, Hkv 8, D 128, bf16), on one described
+    chip; abstract params, nothing allocated."""
     from repro.serve import serve_engine_rules
     from repro.workloads import DecodeEngine, ServeConfig
-    monkeypatch.setattr(ops, "_on_tpu", lambda: True)
     cfg = dataclasses.replace(get_config("minitron-4b"), num_layers=2)
     model = build_model(cfg)
     params = jax.eval_shape(model.init, jax.random.key(0))
@@ -120,6 +116,17 @@ def test_decode_step_updates_stacked_kv_cache_in_place(topo, monkeypatch):
                        rules=serve_engine_rules())
     mesh = Mesh(np.array(topo.devices[:1]).reshape(1, 1), ("data", "model"),
                 axis_types=(AxisType.Auto,) * 2)
+    return eng, mesh
+
+
+def test_decode_step_updates_stacked_kv_cache_in_place(topo, monkeypatch):
+    """DecodeEngine's decode program (see ``_minitron_engine``) with a KV
+    bound of 1024: the layer loop writes one row per slot per layer into
+    the stacked cache where it lies.  No copy and no dynamic-update-slice
+    the size of the stack or of one layer's slice, no temporary that
+    large, and the donated stacks alias the outputs."""
+    monkeypatch.setattr(ops, "_on_tpu", lambda: True)
+    eng, mesh = _minitron_engine(topo)
     compiled = eng._build_decode(mesh, bounds=(1024,))
     text = compiled.as_text()
     assert "tpu_custom_call" in text
@@ -144,6 +151,37 @@ def test_decode_step_updates_stacked_kv_cache_in_place(topo, monkeypatch):
     aliased = set(re.findall(r"\{[\d,]*\}: \((\d+), ", header))
     assert set(stack_params) <= aliased, (stack_params, header[:300])
     assert mem.alias_size_in_bytes >= 2 * 2 * layer_bytes, mem
+
+
+# one layer's Q/K/V weight as the stacked params give it: canonical
+# (d, H, D) and heads-major (H, d, D), with and without the layer axis
+_QKV_SLICES = {f"bf16[{lead}{dims}]" for lead in ("", "1,")
+               for h in (24, 8)
+               for dims in (f"3072,{h},128", f"{h},3072,128")}
+
+
+def test_qkv_weights_stream_into_their_projections(topo, monkeypatch):
+    """The decode program (KV bound 1024) and the prefill program (1536
+    tokens) of ``_minitron_engine``: the compiler lowers each Q/K/V
+    projection as a convolution windowed over heads, which reads its
+    weight heads-major.  The engine holds the weights so, and each layer's
+    slice of the stack fuses into its projection.  Held (d, H, D), each
+    slice would be staged into VMEM (a fusion laid out in ``S(1)``) and
+    relaid out (a copy) every layer of every step."""
+    monkeypatch.setattr(ops, "_on_tpu", lambda: True)
+    eng, mesh = _minitron_engine(topo)
+    decode = eng._build_decode(mesh, bounds=(1024,))
+    programs = {"decode": decode, "prefill": eng._build_prefill(mesh, 1536)}
+    for kind, compiled in programs.items():
+        staged = [(name, shape + layout, op) for name, shape, layout, op
+                  in re.findall(r"%(\S+) = (bf16\[[\d,]+\])(\{[^}]*\})? "
+                                r"([\w-]+)\(", compiled.as_text())
+                  if shape in _QKV_SLICES
+                  and ("copy" in name or op == "copy"
+                       or (op == "fusion" and "S(1)" in layout))]
+        assert not staged, (kind, staged)
+    mem = decode.memory_analysis()
+    assert mem.temp_size_in_bytes < 12 * 2048 * 8 * 128 * 2, mem
 
 
 def _mamba_avals(d_model, d_in, dt_rank, n, w, sharding, B=8):
